@@ -27,6 +27,8 @@ import sys
 import time
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "results")
 
 MODULES = [
@@ -70,6 +72,7 @@ def main() -> None:
     ap.add_argument("--results-dir", default=RESULTS_DIR,
                     help="where BENCH_<n>.json lands")
     args = ap.parse_args()
+    enable_compile_cache()
     modules = SMOKE_MODULES if args.smoke else MODULES
     if args.only:
         modules = [m for m in modules if args.only in m]

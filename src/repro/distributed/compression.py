@@ -40,12 +40,7 @@ def dequantize(q: jax.Array, scale: jax.Array) -> jax.Array:
 def compressed_mean_tree(grads, axis_name: str):
     """Mean of a gradient pytree across ``axis_name`` with int8 payloads.
     Must be called inside shard_map/pmap over that axis."""
-    # jax < 0.6 compat: lax.axis_size landed later; psum of 1 over the named
-    # axis is the classic spelling and constant-folds to the same value.
-    if hasattr(jax.lax, "axis_size"):
-        n = jax.lax.axis_size(axis_name)
-    else:
-        n = jax.lax.psum(1, axis_name)
+    n = jax.lax.axis_size(axis_name)
 
     def one(g):
         q, scale = quantize(g)

@@ -3,22 +3,13 @@ repro.launch.mesh per the assignment; these are the generic utilities)."""
 
 from __future__ import annotations
 
-import inspect
-
 import jax
 from jax.sharding import Mesh
 
-# jax < 0.5 compat: AxisType / make_mesh(axis_types=...) landed later; older
-# versions build Auto meshes by default, so dropping the kwarg is equivalent.
-_HAS_AXIS_TYPES = (hasattr(jax.sharding, "AxisType") and
-                   "axis_types" in inspect.signature(jax.make_mesh).parameters)
-
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
-    if _HAS_AXIS_TYPES:
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def tp_mesh(num_shards: int) -> Mesh:

@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_apply(
@@ -71,9 +70,9 @@ def pipeline_apply(
         return out
 
     stage_spec = jax.tree.map(lambda _: P(axis), stage_params)
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(stage_spec, P()), out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(stage_spec, P()), out_specs=P(),
+                       check_vma=False)
     y_mb = fn(stage_params, x_mb)
     return y_mb.reshape(gb, *y_mb.shape[2:])
 

@@ -215,6 +215,16 @@ COLLECTIVE_PRIMS = frozenset({
 })
 
 
+def _collective_name(name: str) -> str | None:
+    """The census name of a primitive, or None if it is not a collective.
+    ``jax.shard_map(check_vma=True)`` traces a psum as ``psum_invariant``
+    (and likewise for the other varying-manual-axes variants); they count
+    under their plain name, so the census is the same under either
+    setting."""
+    name = name.removesuffix("_invariant")
+    return name if name in COLLECTIVE_PRIMS else None
+
+
 def collective_census(jaxpr) -> dict[str, int]:
     """Count collective primitives in a (closed) jaxpr, recursing through
     every sub-jaxpr (shard_map bodies, scan bodies, custom_vjp branches).
@@ -225,15 +235,13 @@ def collective_census(jaxpr) -> dict[str, int]:
     itself, the paged cache writes, and sampling are communication-free
     because each q-head group is co-located with its kv head.
     """
-    import jax as _jax
-
     counts: dict[str, int] = {}
 
     def _maybe(v):
-        if isinstance(v, _jax.core.ClosedJaxpr):
-            walk(v.jaxpr)
-        elif isinstance(v, _jax.core.Jaxpr):
+        if hasattr(v, "eqns"):              # Jaxpr
             walk(v)
+        elif hasattr(v, "jaxpr"):           # ClosedJaxpr
+            _maybe(v.jaxpr)
         elif isinstance(v, (list, tuple)):
             for x in v:
                 _maybe(x)
@@ -243,11 +251,11 @@ def collective_census(jaxpr) -> dict[str, int]:
 
     def walk(j):
         for eq in j.eqns:
-            name = eq.primitive.name
-            if name in COLLECTIVE_PRIMS:
+            name = _collective_name(eq.primitive.name)
+            if name is not None:
                 counts[name] = counts.get(name, 0) + 1
             for v in eq.params.values():
                 _maybe(v)
 
-    walk(jaxpr.jaxpr if hasattr(jaxpr, "jaxpr") else jaxpr)
+    _maybe(jaxpr)
     return counts

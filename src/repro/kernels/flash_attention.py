@@ -37,7 +37,9 @@ TPU adaptation of the paper's CUDA kernel (see DESIGN.md §2/§3/§6):
     (it rides the custom_vjp residuals in ops.py).
 
 Validated in interpret mode against kernels/ref.py oracles (exact math,
-fp32 accumulation) — see tests/test_kernels_flash.py.
+fp32 accumulation) — see tests/test_kernels_flash.py; lowered for a TPU v5e
+at real widths in tests/test_tpu_compile.py, and run on the chip against
+the same oracles by chip_smoke.py.
 """
 
 from __future__ import annotations
@@ -84,12 +86,15 @@ def _dropout_keep(seed, b, h, q0, k0, bq, bk, num_heads, q_len, k_len, p_drop):
     return r >= threshold
 
 
-def _layout_block(layout_ref):
-    """Read this tile's compiled layout value (static rank-2 or traced
-    rank-3 layout; BlockSpecs deliver a single-element tile either way)."""
-    if len(layout_ref.shape) == 2:
-        return layout_ref[0, 0]
-    return layout_ref[0, 0, 0]
+def _layout_at(lay_ref, layout_shape, b, qi, ki):
+    """This tile's compiled layout class. The layout (static ``(nq, nk)``
+    or traced ``(b, nq, nk)``) is scalar-prefetched into SMEM flattened,
+    so one scalar load serves each grid step."""
+    nq, nk = layout_shape[-2:]
+    idx = qi * nk + ki
+    if len(layout_shape) == 3:
+        idx = b * (nq * nk) + idx
+    return lay_ref[idx]
 
 
 def _tile_mask(qi, ki, bq, bk, q_offset, *, causal, window, kv_valid_len,
@@ -103,11 +108,13 @@ def _tile_mask(qi, ki, bq, bk, q_offset, *, causal, window, kv_valid_len,
     ``kpos_ref`` (traced logical positions, the per-segment-q_offset path)
     the causal/window compare reads the loaded position rows instead of the
     tile iotas (``kv_valid_len`` — a buffer-index term — is excluded by
-    the MaskSpec). Returns None if no term is active.
+    the MaskSpec). Returns None if no term is active. q-side rows arrive as
+    ``(bq, 1)`` columns and kv-side rows as ``(1, bk)`` rows
+    (``_mask_rows``), already in the orientation the mask broadcasts in.
     """
     if qpos_ref is not None:
-        q_pos = qpos_ref[0][:, None]
-        k_pos = kpos_ref[0][None, :]
+        q_pos = qpos_ref[0]
+        k_pos = kpos_ref[0, 0]
     else:
         q_pos = qi * bq + q_offset + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
         k_pos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
@@ -116,9 +123,9 @@ def _tile_mask(qi, ki, bq, bk, q_offset, *, causal, window, kv_valid_len,
         causal=causal if geometry else False,
         window=window if geometry else None,
         kv_valid_len=kv_valid_len,
-        kv_valid=kvm_ref[0][None, :] if kvm_ref is not None else None,
-        q_seg=qseg_ref[0][:, None] if qseg_ref is not None else None,
-        kv_seg=kseg_ref[0][None, :] if kseg_ref is not None else None)
+        kv_valid=kvm_ref[0, 0] != 0 if kvm_ref is not None else None,
+        q_seg=qseg_ref[0] if qseg_ref is not None else None,
+        kv_seg=kseg_ref[0, 0] if kseg_ref is not None else None)
 
 
 def _layout_branches(blk, step, *, causal, window, kv_valid_len,
@@ -149,9 +156,9 @@ def _layout_branches(blk, step, *, causal, window, kv_valid_len,
 # forward kernel
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, layout_ref, kvm_ref, qseg_ref,
+def _fwd_kernel(lay_ref, seed_ref, q_ref, k_ref, v_ref, kvm_ref, qseg_ref,
                 kseg_ref, qpos_ref, kpos_ref, o_ref, m_ref, l_ref,
-                acc_sc, m_sc, l_sc, *,
+                acc_sc, m_sc, l_sc, *, layout_shape,
                 causal, window, q_offset, kv_valid_len, dropout_p,
                 num_heads, q_len, k_len, variant):
     b, h = pl.program_id(0), pl.program_id(1)
@@ -214,8 +221,8 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, layout_ref, kvm_ref, qseg_ref,
         m_sc[...] = jnp.broadcast_to(m_new[:, None], m_sc.shape)
         l_sc[...] = jnp.broadcast_to(l_new[:, None], l_sc.shape)
 
-    _layout_branches(_layout_block(layout_ref), _step, causal=causal,
-                     window=window, kv_valid_len=kv_valid_len,
+    _layout_branches(_layout_at(lay_ref, layout_shape, b, qi, ki), _step,
+                     causal=causal, window=window, kv_valid_len=kv_valid_len,
                      kvm_ref=kvm_ref, qseg_ref=qseg_ref)
 
     @pl.when(ki == nk - 1)
@@ -227,9 +234,153 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, layout_ref, kvm_ref, qseg_ref,
             l_safe = jnp.where(l == 0.0, 1.0, l)
             o = acc_sc[...] / l_safe[:, None]
         o_ref[0, 0] = o.astype(o_ref.dtype)
-        m_ref[0, 0] = m_sc[:, 0]
-        l_ref[0, 0] = l
+        # m/l leave lane-replicated, exactly as the scratch holds them
+        m_ref[0, 0] = m_sc[...]
+        l_ref[0, 0] = l_sc[...]
 
+
+# ---------------------------------------------------------------------------
+# pallas_call assembly shared by the contiguous and paged kernels
+# ---------------------------------------------------------------------------
+#
+# Every call scalar-prefetches (layout, seed[, page table]) into SMEM: the
+# layout is read one scalar per grid step, the seed only under dropout, and
+# the page table by the kv index_maps. Vector operands keep the Mosaic
+# tiling rule (last two block dims divisible by (8, 128) or equal to the
+# array's): the per-row softmax statistics m/l/delta travel lane-replicated
+# as (b, h, s, LANES), q-side mask rows as (b, s, 1) columns and kv-side
+# mask rows as (b, nk, 1, block_k) rows.
+
+def _spec(block_shape, index, q_inner=False):
+    """BlockSpec over a (b, h, q-block, kv-block) grid. ``index`` always
+    sees ``(b, h, qi, ki, *prefetch_refs)``; ``q_inner`` adapts it to the
+    dkv kernels' (b, h, ki, qi) grid order."""
+    if q_inner:
+        return pl.BlockSpec(
+            block_shape, lambda b, h, ki, qi, *pre: index(b, h, qi, ki, *pre))
+    return pl.BlockSpec(block_shape, index)
+
+
+def _kv_spec(block_k, d, n_rep, paged, q_inner=False):
+    """K/V tile spec: a contiguous (b, hkv, sk, d) slice, or — ``paged`` —
+    one pool page resolved through the scalar-prefetched page table."""
+    if paged:
+        return _spec((1, 1, block_k, d),
+                     lambda b, h, qi, ki, lay, seed, tab:
+                     (h // n_rep, tab[b, ki], 0, 0), q_inner)
+    return _spec((1, 1, block_k, d),
+                 lambda b, h, qi, ki, *_: (b, h // n_rep, ki, 0), q_inner)
+
+
+def _q_row_spec(block_q, width, q_inner=False):
+    """(1, 1, block_q, width) blocks of a (b, h, sq, width) array: q/o/do
+    tiles (width d) and the lane-replicated m/l/delta rows (width LANES)."""
+    return _spec((1, 1, block_q, width),
+                 lambda b, h, qi, ki, *_: (b, h, qi, 0), q_inner)
+
+
+def _mask_rows(b, block_q, block_k, q_inner=False, *, kv_mask=None,
+               q_seg=None, kv_seg=None, q_pos=None, kv_pos=None):
+    """(specs, operands) for the optional per-row mask inputs, in
+    ``_split_opts`` order. q-side rows become (b, sq, 1) columns (a
+    (block_q, 1) block per step) and kv-side rows (b, nk, 1, block_k) (a
+    (1, block_k) row per step): legal tilings for any block size, the page
+    size included, and no in-kernel transpose."""
+    q_col = _spec((1, block_q, 1), lambda b, h, qi, ki, *_: (b, qi, 0),
+                  q_inner)
+    k_row = _spec((1, 1, 1, block_k), lambda b, h, qi, ki, *_: (b, ki, 0, 0),
+                  q_inner)
+    specs, args = [], []
+
+    def add(spec, x, shape):
+        specs.append(spec)
+        args.append(jnp.asarray(x, jnp.int32).reshape(shape))
+
+    if kv_mask is not None:
+        add(k_row, kv_mask, (b, -1, 1, block_k))
+    for q_side, kv_side in ((q_seg, kv_seg), (q_pos, kv_pos)):
+        if q_side is not None:
+            add(q_col, q_side, (b, -1, 1))
+            add(k_row, kv_side, (b, -1, 1, block_k))
+    return specs, args
+
+
+def _split_opts(rest, has_kvm, has_seg, has_pos=False):
+    """Route the optional (kvm, qseg, kseg, qpos, kpos) refs from a flat
+    ref tuple."""
+    n_opt = int(has_kvm) + 2 * int(has_seg) + 2 * int(has_pos)
+    opts, rest = rest[:n_opt], rest[n_opt:]
+    kvm_ref = opts[0] if has_kvm else None
+    qseg_ref = opts[int(has_kvm)] if has_seg else None
+    kseg_ref = opts[int(has_kvm) + 1] if has_seg else None
+    base = int(has_kvm) + 2 * int(has_seg)
+    qpos_ref = opts[base] if has_pos else None
+    kpos_ref = opts[base + 1] if has_pos else None
+    return kvm_ref, qseg_ref, kseg_ref, qpos_ref, kpos_ref, rest
+
+
+def _flash_call(kernel, *, n_fixed, grid, block_layout, dropout_seed,
+                table, in_specs, args, rows, out_specs, out_shape, scratch,
+                has_kvm, has_seg, has_pos, interpret):
+    """One fwd/dq/dkv pallas_call. ``kernel`` takes (lay_ref, seed_ref,
+    *n_fixed refs, kvm, qseg, kseg, qpos, kpos, *outputs, *scratch), with
+    None for the absent mask rows; ``table`` (b, T) is the page table of
+    a paged call, else None."""
+    prefetch = [jnp.asarray(block_layout, jnp.int32).reshape(-1),
+                jnp.asarray(dropout_seed, jnp.uint32).reshape(1)]
+    if table is not None:
+        prefetch.append(table)
+    n_pre = len(prefetch)
+
+    def wrapped(*refs):
+        lay_ref, seed_ref = refs[0], refs[1]
+        refs = refs[n_pre:]
+        fixed = refs[:n_fixed]
+        kvm_ref, qseg_ref, kseg_ref, qpos_ref, kpos_ref, rest = _split_opts(
+            refs[n_fixed:], has_kvm, has_seg, has_pos)
+        return kernel(lay_ref, seed_ref, *fixed, kvm_ref, qseg_ref, kseg_ref,
+                      qpos_ref, kpos_ref, *rest)
+
+    return pl.pallas_call(
+        wrapped,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=n_pre, grid=grid,
+            in_specs=in_specs + rows[0], out_specs=out_specs,
+            scratch_shapes=scratch),
+        out_shape=out_shape,
+        interpret=interpret,
+    )(*prefetch, *args, *rows[1])
+
+
+def _forward(q, k, v, block_layout, table, *, block_q, block_k, sk,
+             kv_mask, q_segment_ids, kv_segment_ids, q_positions,
+             kv_positions, dropout_seed, interpret, **static):
+    """(o, m, l) of the forward kernel over a contiguous (``table`` None)
+    or paged kv source; ``q`` arrives pre-scaled."""
+    b, hq, sq, d = q.shape
+    n_rep = hq // k.shape[0 if table is not None else 1]
+    has_seg, has_pos = q_segment_ids is not None, q_positions is not None
+    q_spec = _q_row_spec(block_q, d)
+    stat = _q_row_spec(block_q, LANES)
+    kv_spec = _kv_spec(block_k, d, n_rep, table is not None)
+    return _flash_call(
+        functools.partial(_fwd_kernel, layout_shape=block_layout.shape,
+                          num_heads=hq, **static),
+        n_fixed=3, grid=(b, hq, sq // block_q, sk // block_k),
+        block_layout=block_layout, dropout_seed=dropout_seed, table=table,
+        in_specs=[q_spec, kv_spec, kv_spec], args=[q, k, v],
+        rows=_mask_rows(b, block_q, block_k, kv_mask=kv_mask,
+                        q_seg=q_segment_ids, kv_seg=kv_segment_ids,
+                        q_pos=q_positions, kv_pos=kv_positions),
+        out_specs=[q_spec, stat, stat],
+        out_shape=[jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
+                   jax.ShapeDtypeStruct((b, hq, sq, LANES), jnp.float32),
+                   jax.ShapeDtypeStruct((b, hq, sq, LANES), jnp.float32)],
+        scratch=[pltpu.VMEM((block_q, d), jnp.float32),
+                 pltpu.VMEM((block_q, LANES), jnp.float32),
+                 pltpu.VMEM((block_q, LANES), jnp.float32)],
+        has_kvm=kv_mask is not None, has_seg=has_seg, has_pos=has_pos,
+        interpret=interpret)
 
 
 def flash_attention_forward(
@@ -246,9 +397,10 @@ def flash_attention_forward(
     kv_segment_ids: jax.Array | None = None,
     q_positions: jax.Array | None = None,
     kv_positions: jax.Array | None = None,
-    interpret: bool = True,
+    interpret: bool,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Returns (o, m, l). Shapes: q (b,hq,sq,d), k/v (b,hkv,sk,d),
+    """Returns (o, m, l): o (b,hq,sq,d), m/l (b,hq,sq,LANES) lane-replicated
+    row statistics. Shapes: q (b,hq,sq,d), k/v (b,hkv,sk,d),
     kv_mask (b, sk) or None. sq % block_q == 0 and sk % block_k == 0
     (ops.py pads). ``block_layout`` is the COMPILED layout from
     ``core.masks.compile_block_layout`` — (nq, nk) int32 static or
@@ -261,103 +413,19 @@ def flash_attention_forward(
     dropout_seed may be a traced scalar (no retrace per step);
     dropout_dims = (orig_q_len, orig_k_len) keeps the counter-based
     dropout hash independent of padding."""
-    b, hq, sq, d = q.shape
-    _, hkv, sk, _ = k.shape
-    n_rep = hq // hkv
-    nq, nk = sq // block_q, sk // block_k
+    sq, sk = q.shape[2], k.shape[2]
     if q_positions is not None and kv_valid_len is not None:
         raise ValueError("kv_valid_len cannot combine with q/kv_positions")
     dq_len, dk_len = dropout_dims if dropout_dims is not None else (sq, sk)
-    seed_arr = jnp.asarray(dropout_seed, jnp.uint32).reshape(1)
-    q = q * scale  # FA-2 hoist: one multiply at the XLA level, not per tile
-
-    kernel = functools.partial(
-        _fwd_kernel, causal=causal, window=window,
-        q_offset=q_offset, kv_valid_len=kv_valid_len, dropout_p=dropout_p,
-        num_heads=hq, q_len=dq_len, k_len=dk_len, variant=variant)
-
-    in_specs = [
-        pl.BlockSpec((1,), lambda b, h, qi, ki: (0,)),
-        pl.BlockSpec((1, 1, block_q, d), lambda b, h, qi, ki: (b, h, qi, 0)),
-        pl.BlockSpec((1, 1, block_k, d), lambda b, h, qi, ki: (b, h // n_rep, ki, 0)),
-        pl.BlockSpec((1, 1, block_k, d), lambda b, h, qi, ki: (b, h // n_rep, ki, 0)),
-        _layout_spec(block_layout),
-    ]
-    args = [seed_arr, q, k, v, block_layout]
-    has_kvm = kv_mask is not None
-    has_seg = q_segment_ids is not None
-    has_pos = q_positions is not None
-    if has_kvm:
-        in_specs.append(pl.BlockSpec((1, block_k), lambda b, h, qi, ki: (b, ki)))
-        args.append(kv_mask)
-    if has_seg:
-        in_specs.append(pl.BlockSpec((1, block_q), lambda b, h, qi, ki: (b, qi)))
-        args.append(q_segment_ids)
-        in_specs.append(pl.BlockSpec((1, block_k), lambda b, h, qi, ki: (b, ki)))
-        args.append(kv_segment_ids)
-    if has_pos:
-        in_specs.append(pl.BlockSpec((1, block_q), lambda b, h, qi, ki: (b, qi)))
-        args.append(q_positions)
-        in_specs.append(pl.BlockSpec((1, block_k), lambda b, h, qi, ki: (b, ki)))
-        args.append(kv_positions)
-
-    def wrapped(seed_ref, q_ref, k_ref, v_ref, layout_ref, *rest):
-        kvm_ref, qseg_ref, kseg_ref, qpos_ref, kpos_ref, rest = _split_opts(
-            rest, has_kvm, has_seg, has_pos)
-        return kernel(seed_ref, q_ref, k_ref, v_ref, layout_ref, kvm_ref,
-                      qseg_ref, kseg_ref, qpos_ref, kpos_ref, *rest)
-
-    out_specs = [
-        pl.BlockSpec((1, 1, block_q, d), lambda b, h, qi, ki: (b, h, qi, 0)),
-        pl.BlockSpec((1, 1, block_q), lambda b, h, qi, ki: (b, h, qi)),
-        pl.BlockSpec((1, 1, block_q), lambda b, h, qi, ki: (b, h, qi)),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
-        jax.ShapeDtypeStruct((b, hq, sq), jnp.float32),
-        jax.ShapeDtypeStruct((b, hq, sq), jnp.float32),
-    ]
-    scratch = [
-        pltpu.VMEM((block_q, d), jnp.float32),
-        pltpu.VMEM((block_q, LANES), jnp.float32),
-        pltpu.VMEM((block_q, LANES), jnp.float32),
-    ]
-    o, m, l = pl.pallas_call(
-        wrapped,
-        grid=(b, hq, nq, nk),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=scratch,
-        interpret=interpret,
-    )(*args)
-    return o, m, l
-
-
-def _layout_spec(block_layout, kv_major: bool = False):
-    """BlockSpec delivering one layout value per grid step. ``kv_major``
-    matches the dkv kernel's (b, h, ki, qi) grid order."""
-    if block_layout.ndim == 2:
-        if kv_major:
-            return pl.BlockSpec((1, 1), lambda b, h, ki, qi: (qi, ki))
-        return pl.BlockSpec((1, 1), lambda b, h, qi, ki: (qi, ki))
-    if kv_major:
-        return pl.BlockSpec((1, 1, 1), lambda b, h, ki, qi: (b, qi, ki))
-    return pl.BlockSpec((1, 1, 1), lambda b, h, qi, ki: (b, qi, ki))
-
-
-def _split_opts(rest, has_kvm, has_seg, has_pos=False):
-    """Route the optional (kvm, qseg, kseg, qpos, kpos) refs from a flat
-    ref tuple."""
-    n_opt = int(has_kvm) + 2 * int(has_seg) + 2 * int(has_pos)
-    opts, rest = rest[:n_opt], rest[n_opt:]
-    kvm_ref = opts[0] if has_kvm else None
-    qseg_ref = opts[int(has_kvm)] if has_seg else None
-    kseg_ref = opts[int(has_kvm) + 1] if has_seg else None
-    base = int(has_kvm) + 2 * int(has_seg)
-    qpos_ref = opts[base] if has_pos else None
-    kpos_ref = opts[base + 1] if has_pos else None
-    return kvm_ref, qseg_ref, kseg_ref, qpos_ref, kpos_ref, rest
+    # FA-2 hoist: one multiply at the XLA level, not per tile
+    return _forward(
+        q * scale, k, v, block_layout, None, block_q=block_q,
+        block_k=block_k, sk=sk, kv_mask=kv_mask,
+        q_segment_ids=q_segment_ids, kv_segment_ids=kv_segment_ids,
+        q_positions=q_positions, kv_positions=kv_positions,
+        dropout_seed=dropout_seed, interpret=interpret, causal=causal,
+        window=window, q_offset=q_offset, kv_valid_len=kv_valid_len,
+        dropout_p=dropout_p, q_len=dq_len, k_len=dk_len, variant=variant)
 
 
 # ---------------------------------------------------------------------------
@@ -379,9 +447,9 @@ def _recompute_p(q, k, m_row, l_row, ok):
     return p
 
 
-def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, dd_ref,
-               layout_ref, kvm_ref, qseg_ref, kseg_ref, qpos_ref, kpos_ref,
-               dq_ref, dq_sc, *,
+def _dq_kernel(lay_ref, seed_ref, q_ref, k_ref, v_ref, do_ref, m_ref, l_ref,
+               dd_ref, kvm_ref, qseg_ref, kseg_ref, qpos_ref, kpos_ref,
+               dq_ref, dq_sc, *, layout_shape,
                scale, causal, window, q_offset, kv_valid_len, dropout_p,
                num_heads, q_len, k_len):
     b, h = pl.program_id(0), pl.program_id(1)
@@ -399,7 +467,9 @@ def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, dd_ref,
         k = k_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
         do = do_ref[0, 0].astype(jnp.float32)
-        m_row, l_row, dd = m_ref[0, 0], l_ref[0, 0], dd_ref[0, 0]
+        # lane-replicated row statistics: column 0 carries the value
+        m_row, l_row = m_ref[0, 0][:, 0], l_ref[0, 0][:, 0]
+        dd = dd_ref[0, 0][:, 0]
         ok = None
         if mode != "none":
             ok = _tile_mask(qi, ki, bq, bk, q_offset, causal=causal,
@@ -418,8 +488,8 @@ def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, dd_ref,
         dq_sc[...] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
-    _layout_branches(_layout_block(layout_ref), _step, causal=causal,
-                     window=window, kv_valid_len=kv_valid_len,
+    _layout_branches(_layout_at(lay_ref, layout_shape, b, qi, ki), _step,
+                     causal=causal, window=window, kv_valid_len=kv_valid_len,
                      kvm_ref=kvm_ref, qseg_ref=qseg_ref)
 
     @pl.when(ki == nk - 1)
@@ -433,9 +503,9 @@ def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, dd_ref,
 # backward: dkv kernel (grid over kv blocks, q innermost)
 # ---------------------------------------------------------------------------
 
-def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, dd_ref,
-                layout_ref, kvm_ref, qseg_ref, kseg_ref, qpos_ref, kpos_ref,
-                dk_ref, dv_ref, dk_sc, dv_sc, *,
+def _dkv_kernel(lay_ref, seed_ref, q_ref, k_ref, v_ref, do_ref, m_ref, l_ref,
+                dd_ref, kvm_ref, qseg_ref, kseg_ref, qpos_ref, kpos_ref,
+                dk_ref, dv_ref, dk_sc, dv_sc, *, layout_shape,
                 causal, window, q_offset, kv_valid_len, dropout_p,
                 num_heads, q_len, k_len):
     b, h = pl.program_id(0), pl.program_id(1)
@@ -454,7 +524,9 @@ def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, dd_ref,
         k = k_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
         do = do_ref[0, 0].astype(jnp.float32)
-        m_row, l_row, dd = m_ref[0, 0], l_ref[0, 0], dd_ref[0, 0]
+        # lane-replicated row statistics: column 0 carries the value
+        m_row, l_row = m_ref[0, 0][:, 0], l_ref[0, 0][:, 0]
+        dd = dd_ref[0, 0][:, 0]
         ok = None
         if mode != "none":
             ok = _tile_mask(qi, ki, bq, bk, q_offset, causal=causal,
@@ -485,14 +557,75 @@ def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, dd_ref,
         dk_sc[...] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
-    _layout_branches(_layout_block(layout_ref), _step, causal=causal,
-                     window=window, kv_valid_len=kv_valid_len,
+    _layout_branches(_layout_at(lay_ref, layout_shape, b, qi, ki), _step,
+                     causal=causal, window=window, kv_valid_len=kv_valid_len,
                      kvm_ref=kvm_ref, qseg_ref=qseg_ref)
 
     @pl.when(qi == nq - 1)
     def _finalize():
         dk_ref[0, 0] = dk_sc[...].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_sc[...].astype(dv_ref.dtype)
+
+
+def _backward(q, k, v, o, do, m, l, block_layout, table, *, scale, block_q,
+              block_k, sk, kv_mask, q_segment_ids, kv_segment_ids,
+              q_positions, kv_positions, dropout_seed, interpret, **static):
+    """dq and the PER-Q-HEAD (dk, dv) — (b, hq, sk, d) float32, sk being
+    the packed page-aligned length for a paged call — from the dq and dkv
+    kernels over a contiguous (``table`` None) or paged kv source."""
+    b, hq, sq, d = q.shape
+    n_rep = hq // k.shape[0 if table is not None else 1]
+    nq, nk = sq // block_q, sk // block_k
+    paged = table is not None
+    flags = dict(has_kvm=kv_mask is not None,
+                 has_seg=q_segment_ids is not None,
+                 has_pos=q_positions is not None)
+    rows = dict(kv_mask=kv_mask, q_seg=q_segment_ids, kv_seg=kv_segment_ids,
+                q_pos=q_positions, kv_pos=kv_positions)
+
+    # D_i = rowsum(dO ∘ O) (paper Eq. 4 / Alg. 4 line 19). O(Nd) IO, done at
+    # the XLA level (fuses with surrounding ops); lane-replicated like m/l.
+    dd = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    dd = jnp.broadcast_to(dd[..., None], (b, hq, sq, LANES))
+
+    # Same folded scale as the forward: both kernels recompute P from the
+    # pre-scaled q; the dq kernel applies the chain-rule scale at finalize
+    # and the dkv kernel needs none (dK = dS^T q' is already scaled).
+    q = q * scale
+    args = [q, k, v, do, m, l, dd]
+    common = dict(block_layout=block_layout, dropout_seed=dropout_seed,
+                  table=table, args=args, interpret=interpret, **flags)
+    kernel_static = dict(layout_shape=block_layout.shape, num_heads=hq,
+                         **static)
+
+    def in_specs(q_inner):
+        q_spec = _q_row_spec(block_q, d, q_inner)
+        stat = _q_row_spec(block_q, LANES, q_inner)
+        kv_spec = _kv_spec(block_k, d, n_rep, paged, q_inner)
+        return [q_spec, kv_spec, kv_spec, q_spec, stat, stat, stat]
+
+    # ---- dq kernel: grid over q blocks, kv innermost ----
+    dq = _flash_call(
+        functools.partial(_dq_kernel, scale=scale, **kernel_static),
+        n_fixed=7, grid=(b, hq, nq, nk), in_specs=in_specs(False),
+        rows=_mask_rows(b, block_q, block_k, **rows),
+        out_specs=_q_row_spec(block_q, d),
+        out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
+        scratch=[pltpu.VMEM((block_q, d), jnp.float32)], **common)
+
+    # ---- dkv kernel: grid over kv blocks, q innermost ----
+    kv_out = _spec((1, 1, block_k, d),
+                   lambda b, h, qi, ki, *_: (b, h, ki, 0), q_inner=True)
+    dk_p, dv_p = _flash_call(
+        functools.partial(_dkv_kernel, **kernel_static),
+        n_fixed=7, grid=(b, hq, nk, nq), in_specs=in_specs(True),
+        rows=_mask_rows(b, block_q, block_k, q_inner=True, **rows),
+        out_specs=[kv_out, kv_out],
+        out_shape=[jax.ShapeDtypeStruct((b, hq, sk, d), jnp.float32),
+                   jax.ShapeDtypeStruct((b, hq, sk, d), jnp.float32)],
+        scratch=[pltpu.VMEM((block_k, d), jnp.float32),
+                 pltpu.VMEM((block_k, d), jnp.float32)], **common)
+    return dq, dk_p, dv_p
 
 
 def flash_attention_backward(
@@ -505,128 +638,22 @@ def flash_attention_backward(
     kv_segment_ids: jax.Array | None = None,
     q_positions: jax.Array | None = None,
     kv_positions: jax.Array | None = None,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Returns (dq, dk, dv) with dk/dv already group-summed for GQA.
     ``block_layout`` is the same compiled layout the forward ran with."""
     b, hq, sq, d = q.shape
     _, hkv, sk, _ = k.shape
     n_rep = hq // hkv
-    nq, nk = sq // block_q, sk // block_k
     dq_len, dk_len = dropout_dims if dropout_dims is not None else (sq, sk)
-    has_kvm = kv_mask is not None
-    has_seg = q_segment_ids is not None
-    has_pos = q_positions is not None
-    seed_arr = jnp.asarray(dropout_seed, jnp.uint32).reshape(1)
-
-    # D_i = rowsum(dO ∘ O) (paper Eq. 4 / Alg. 4 line 19). O(Nd) IO, done at
-    # the XLA level (fuses with surrounding ops).
-    dd = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-
-    # Same folded scale as the forward: both kernels recompute P from the
-    # pre-scaled q; the dq kernel applies the chain-rule scale at finalize
-    # and the dkv kernel needs none (dK = dS^T q' is already scaled).
-    q = q * scale
-
-    common = dict(causal=causal, window=window, q_offset=q_offset,
-                  kv_valid_len=kv_valid_len, dropout_p=dropout_p,
-                  num_heads=hq, q_len=dq_len, k_len=dk_len)
-
-    def _route(kernel, n_fixed):
-        def wrapped(*refs):
-            fixed = refs[:n_fixed]
-            kvm_ref, qseg_ref, kseg_ref, qpos_ref, kpos_ref, rest = \
-                _split_opts(refs[n_fixed:], has_kvm, has_seg, has_pos)
-            return kernel(*fixed, kvm_ref, qseg_ref, kseg_ref, qpos_ref,
-                          kpos_ref, *rest)
-        return wrapped
-
-    def _append_opts(in_specs, args, kvm_spec, qseg_spec, kseg_spec):
-        if has_kvm:
-            in_specs.append(kvm_spec)
-            args.append(kv_mask)
-        if has_seg:
-            in_specs.append(qseg_spec)
-            args.append(q_segment_ids)
-            in_specs.append(kseg_spec)
-            args.append(kv_segment_ids)
-        if has_pos:
-            # positions ride the same q-row / kv-row BlockSpecs as the ids
-            in_specs.append(qseg_spec)
-            args.append(q_positions)
-            in_specs.append(kseg_spec)
-            args.append(kv_positions)
-
-    # ---- dq kernel ----
-    dq_kernel = functools.partial(_dq_kernel, scale=scale, **common)
-    in_specs = [
-        pl.BlockSpec((1,), lambda b, h, qi, ki: (0,)),
-        pl.BlockSpec((1, 1, block_q, d), lambda b, h, qi, ki: (b, h, qi, 0)),
-        pl.BlockSpec((1, 1, block_k, d), lambda b, h, qi, ki: (b, h // n_rep, ki, 0)),
-        pl.BlockSpec((1, 1, block_k, d), lambda b, h, qi, ki: (b, h // n_rep, ki, 0)),
-        pl.BlockSpec((1, 1, block_q, d), lambda b, h, qi, ki: (b, h, qi, 0)),
-        pl.BlockSpec((1, 1, block_q), lambda b, h, qi, ki: (b, h, qi)),
-        pl.BlockSpec((1, 1, block_q), lambda b, h, qi, ki: (b, h, qi)),
-        pl.BlockSpec((1, 1, block_q), lambda b, h, qi, ki: (b, h, qi)),
-        _layout_spec(block_layout),
-    ]
-    args = [seed_arr, q, k, v, do, m, l, dd, block_layout]
-    _append_opts(
-        in_specs, args,
-        pl.BlockSpec((1, block_k), lambda b, h, qi, ki: (b, ki)),
-        pl.BlockSpec((1, block_q), lambda b, h, qi, ki: (b, qi)),
-        pl.BlockSpec((1, block_k), lambda b, h, qi, ki: (b, ki)))
-    dq_wrapped = _route(dq_kernel, 9)
-
-    dq = pl.pallas_call(
-        dq_wrapped,
-        grid=(b, hq, nq, nk),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, block_q, d), lambda b, h, qi, ki: (b, h, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=interpret,
-    )(*args)
-
-    # ---- dkv kernel ----
-    dkv_kernel = functools.partial(_dkv_kernel, **common)
-    in_specs = [
-        pl.BlockSpec((1,), lambda b, h, ki, qi: (0,)),
-        pl.BlockSpec((1, 1, block_q, d), lambda b, h, ki, qi: (b, h, qi, 0)),
-        pl.BlockSpec((1, 1, block_k, d), lambda b, h, ki, qi: (b, h // n_rep, ki, 0)),
-        pl.BlockSpec((1, 1, block_k, d), lambda b, h, ki, qi: (b, h // n_rep, ki, 0)),
-        pl.BlockSpec((1, 1, block_q, d), lambda b, h, ki, qi: (b, h, qi, 0)),
-        pl.BlockSpec((1, 1, block_q), lambda b, h, ki, qi: (b, h, qi)),
-        pl.BlockSpec((1, 1, block_q), lambda b, h, ki, qi: (b, h, qi)),
-        pl.BlockSpec((1, 1, block_q), lambda b, h, ki, qi: (b, h, qi)),
-        _layout_spec(block_layout, kv_major=True),
-    ]
-    args = [seed_arr, q, k, v, do, m, l, dd, block_layout]
-    _append_opts(
-        in_specs, args,
-        pl.BlockSpec((1, block_k), lambda b, h, ki, qi: (b, ki)),
-        pl.BlockSpec((1, block_q), lambda b, h, ki, qi: (b, qi)),
-        pl.BlockSpec((1, block_k), lambda b, h, ki, qi: (b, ki)))
-    dkv_wrapped = _route(dkv_kernel, 9)
-
-    dk_p, dv_p = pl.pallas_call(
-        dkv_wrapped,
-        grid=(b, hq, nk, nq),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, block_k, d), lambda b, h, ki, qi: (b, h, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda b, h, ki, qi: (b, h, ki, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, hq, sk, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, hq, sk, d), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
-        ],
-        interpret=interpret,
-    )(*args)
+    dq, dk_p, dv_p = _backward(
+        q, k, v, o, do, m, l, block_layout, None, scale=scale,
+        block_q=block_q, block_k=block_k, sk=sk, kv_mask=kv_mask,
+        q_segment_ids=q_segment_ids, kv_segment_ids=kv_segment_ids,
+        q_positions=q_positions, kv_positions=kv_positions,
+        dropout_seed=dropout_seed, interpret=interpret, causal=causal,
+        window=window, q_offset=q_offset, kv_valid_len=kv_valid_len,
+        dropout_p=dropout_p, q_len=dq_len, k_len=dk_len)
 
     if n_rep > 1:  # GQA: sum gradients over the query-head group
         dk = dk_p.reshape(b, hkv, n_rep, sk, d).sum(axis=2)
@@ -689,90 +716,24 @@ def flash_prefill_paged_forward(
     q_positions: jax.Array | None = None,
     kv_positions: jax.Array | None = None,
     block_q: int, variant: str = "fa2",
-    interpret: bool = True,
+    interpret: bool,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Returns (o, m, l) — the same residuals as the contiguous forward,
     computed directly against pool pages. Reuses ``_fwd_kernel`` verbatim:
     only the kv BlockSpecs change (page indirection instead of a
     contiguous slice), which is the whole point — the loop body, the
     online-softmax state, and the layout-branch dispatch are untouched."""
-    b, hq, sq, d = q.shape
-    hkv, num_pages, ps, _ = k_pool.shape
-    n_rep = hq // hkv
-    T = page_list.shape[1]
-    nq = sq // block_q
-    q = q * scale  # folded scale, as in the contiguous forward
-    seed_arr = jnp.zeros((1,), jnp.uint32)  # serving path: dropout_p == 0
+    ps = k_pool.shape[2]
+    sk = page_list.shape[1] * ps
     table = jnp.maximum(page_list, 0).astype(jnp.int32)
-
-    kernel = functools.partial(
-        _fwd_kernel, causal=causal, window=window, q_offset=0,
-        kv_valid_len=None, dropout_p=0.0, num_heads=hq, q_len=sq,
-        k_len=T * ps, variant=variant)
-
-    has_seg = q_segment_ids is not None
-    has_pos = q_positions is not None
-
-    in_specs = [
-        pl.BlockSpec((1,), lambda b, h, qi, ki, tab: (0,)),
-        pl.BlockSpec((1, 1, block_q, d),
-                     lambda b, h, qi, ki, tab: (b, h, qi, 0)),
-        pl.BlockSpec((1, 1, ps, d),
-                     lambda b, h, qi, ki, tab: (h // n_rep, tab[b, ki], 0, 0)),
-        pl.BlockSpec((1, 1, ps, d),
-                     lambda b, h, qi, ki, tab: (h // n_rep, tab[b, ki], 0, 0)),
-        pl.BlockSpec((1, 1, 1), lambda b, h, qi, ki, tab: (b, qi, ki)),
-    ]
-    args = [seed_arr, q, k_pool, v_pool, block_layout]
-    if has_seg:
-        in_specs.append(
-            pl.BlockSpec((1, block_q), lambda b, h, qi, ki, tab: (b, qi)))
-        args.append(q_segment_ids)
-        in_specs.append(
-            pl.BlockSpec((1, ps), lambda b, h, qi, ki, tab: (b, ki)))
-        args.append(kv_segment_ids)
-    if has_pos:
-        in_specs.append(
-            pl.BlockSpec((1, block_q), lambda b, h, qi, ki, tab: (b, qi)))
-        args.append(q_positions)
-        in_specs.append(
-            pl.BlockSpec((1, ps), lambda b, h, qi, ki, tab: (b, ki)))
-        args.append(kv_positions)
-
-    def wrapped(tab_ref, seed_ref, q_ref, k_ref, v_ref, layout_ref, *rest):
-        del tab_ref  # consumed by the index_maps only
-        kvm_ref, qseg_ref, kseg_ref, qpos_ref, kpos_ref, rest = _split_opts(
-            rest, False, has_seg, has_pos)
-        return kernel(seed_ref, q_ref, k_ref, v_ref, layout_ref, kvm_ref,
-                      qseg_ref, kseg_ref, qpos_ref, kpos_ref, *rest)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, hq, nq, T),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, block_q, d),
-                         lambda b, h, qi, ki, tab: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, qi, ki, tab: (b, h, qi)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, qi, ki, tab: (b, h, qi)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q, LANES), jnp.float32),
-            pltpu.VMEM((block_q, LANES), jnp.float32),
-        ],
-    )
-    o, m, l = pl.pallas_call(
-        wrapped,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((b, hq, sq), jnp.float32),
-            jax.ShapeDtypeStruct((b, hq, sq), jnp.float32),
-        ],
-        interpret=interpret,
-    )(table, *args)
-    return o, m, l
+    # folded scale, as in the contiguous forward; serving path: no dropout
+    return _forward(
+        q * scale, k_pool, v_pool, block_layout, table, block_q=block_q,
+        block_k=ps, sk=sk, kv_mask=None, q_segment_ids=q_segment_ids,
+        kv_segment_ids=kv_segment_ids, q_positions=q_positions,
+        kv_positions=kv_positions, dropout_seed=0, interpret=interpret,
+        causal=causal, window=window, q_offset=0, kv_valid_len=None,
+        dropout_p=0.0, q_len=q.shape[2], k_len=sk, variant=variant)
 
 
 def flash_prefill_paged_backward(
@@ -781,7 +742,7 @@ def flash_prefill_paged_backward(
     scale: float, causal: bool, window: int | None,
     q_segment_ids=None, kv_segment_ids=None,
     q_positions=None, kv_positions=None,
-    block_q: int, interpret: bool = True,
+    block_q: int, interpret: bool,
 ):
     """dq/dkv pair for the paged prefill (trainable use). The dq kernel
     reads pool pages through the same scalar-prefetched indirection as the
@@ -794,129 +755,14 @@ def flash_prefill_paged_backward(
     hkv, num_pages, ps, _ = k_pool.shape
     n_rep = hq // hkv
     T = page_list.shape[1]
-    nq = sq // block_q
-    has_seg = q_segment_ids is not None
-    has_pos = q_positions is not None
-    seed_arr = jnp.zeros((1,), jnp.uint32)
     table = jnp.maximum(page_list, 0).astype(jnp.int32)
-
-    dd = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    q = q * scale  # folded scale (see flash_attention_backward)
-
-    common = dict(causal=causal, window=window, q_offset=0, kv_valid_len=None,
-                  dropout_p=0.0, num_heads=hq, q_len=sq, k_len=T * ps)
-
-    def _route_paged(kernel):
-        def wrapped(tab_ref, *refs):
-            del tab_ref
-            fixed = refs[:9]
-            kvm_ref, qseg_ref, kseg_ref, qpos_ref, kpos_ref, rest = \
-                _split_opts(refs[9:], False, has_seg, has_pos)
-            return kernel(*fixed, kvm_ref, qseg_ref, kseg_ref, qpos_ref,
-                          kpos_ref, *rest)
-        return wrapped
-
-    # ---- dq: q-major grid, kv pages indirected ----
-    in_specs = [
-        pl.BlockSpec((1,), lambda b, h, qi, ki, tab: (0,)),
-        pl.BlockSpec((1, 1, block_q, d),
-                     lambda b, h, qi, ki, tab: (b, h, qi, 0)),
-        pl.BlockSpec((1, 1, ps, d),
-                     lambda b, h, qi, ki, tab: (h // n_rep, tab[b, ki], 0, 0)),
-        pl.BlockSpec((1, 1, ps, d),
-                     lambda b, h, qi, ki, tab: (h // n_rep, tab[b, ki], 0, 0)),
-        pl.BlockSpec((1, 1, block_q, d),
-                     lambda b, h, qi, ki, tab: (b, h, qi, 0)),
-        pl.BlockSpec((1, 1, block_q), lambda b, h, qi, ki, tab: (b, h, qi)),
-        pl.BlockSpec((1, 1, block_q), lambda b, h, qi, ki, tab: (b, h, qi)),
-        pl.BlockSpec((1, 1, block_q), lambda b, h, qi, ki, tab: (b, h, qi)),
-        pl.BlockSpec((1, 1, 1), lambda b, h, qi, ki, tab: (b, qi, ki)),
-    ]
-    args = [seed_arr, q, k_pool, v_pool, do, m, l, dd, block_layout]
-    if has_seg:
-        in_specs.append(
-            pl.BlockSpec((1, block_q), lambda b, h, qi, ki, tab: (b, qi)))
-        args.append(q_segment_ids)
-        in_specs.append(
-            pl.BlockSpec((1, ps), lambda b, h, qi, ki, tab: (b, ki)))
-        args.append(kv_segment_ids)
-    if has_pos:
-        in_specs.append(
-            pl.BlockSpec((1, block_q), lambda b, h, qi, ki, tab: (b, qi)))
-        args.append(q_positions)
-        in_specs.append(
-            pl.BlockSpec((1, ps), lambda b, h, qi, ki, tab: (b, ki)))
-        args.append(kv_positions)
-
-    dq = pl.pallas_call(
-        _route_paged(functools.partial(_dq_kernel, scale=scale, **common)),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(b, hq, nq, T),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, 1, block_q, d),
-                                   lambda b, h, qi, ki, tab: (b, h, qi, 0)),
-            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
-        interpret=interpret,
-    )(table, *args)
-
-    # ---- dkv: kv-major grid over page slots, packed outputs ----
-    in_specs = [
-        pl.BlockSpec((1,), lambda b, h, ki, qi, tab: (0,)),
-        pl.BlockSpec((1, 1, block_q, d),
-                     lambda b, h, ki, qi, tab: (b, h, qi, 0)),
-        pl.BlockSpec((1, 1, ps, d),
-                     lambda b, h, ki, qi, tab: (h // n_rep, tab[b, ki], 0, 0)),
-        pl.BlockSpec((1, 1, ps, d),
-                     lambda b, h, ki, qi, tab: (h // n_rep, tab[b, ki], 0, 0)),
-        pl.BlockSpec((1, 1, block_q, d),
-                     lambda b, h, ki, qi, tab: (b, h, qi, 0)),
-        pl.BlockSpec((1, 1, block_q), lambda b, h, ki, qi, tab: (b, h, qi)),
-        pl.BlockSpec((1, 1, block_q), lambda b, h, ki, qi, tab: (b, h, qi)),
-        pl.BlockSpec((1, 1, block_q), lambda b, h, ki, qi, tab: (b, h, qi)),
-        pl.BlockSpec((1, 1, 1), lambda b, h, ki, qi, tab: (b, qi, ki)),
-    ]
-    args = [seed_arr, q, k_pool, v_pool, do, m, l, dd, block_layout]
-    if has_seg:
-        in_specs.append(
-            pl.BlockSpec((1, block_q), lambda b, h, ki, qi, tab: (b, qi)))
-        args.append(q_segment_ids)
-        in_specs.append(
-            pl.BlockSpec((1, ps), lambda b, h, ki, qi, tab: (b, ki)))
-        args.append(kv_segment_ids)
-    if has_pos:
-        in_specs.append(
-            pl.BlockSpec((1, block_q), lambda b, h, ki, qi, tab: (b, qi)))
-        args.append(q_positions)
-        in_specs.append(
-            pl.BlockSpec((1, ps), lambda b, h, ki, qi, tab: (b, ki)))
-        args.append(kv_positions)
-
-    dk_pk, dv_pk = pl.pallas_call(
-        _route_paged(functools.partial(_dkv_kernel, **common)),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(b, hq, T, nq),
-            in_specs=in_specs,
-            out_specs=[
-                pl.BlockSpec((1, 1, ps, d),
-                             lambda b, h, ki, qi, tab: (b, h, ki, 0)),
-                pl.BlockSpec((1, 1, ps, d),
-                             lambda b, h, ki, qi, tab: (b, h, ki, 0)),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((ps, d), jnp.float32),
-                pltpu.VMEM((ps, d), jnp.float32),
-            ],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((b, hq, T * ps, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, hq, T * ps, d), jnp.float32),
-        ],
-        interpret=interpret,
-    )(table, *args)
+    dq, dk_pk, dv_pk = _backward(
+        q, k_pool, v_pool, o, do, m, l, block_layout, table, scale=scale,
+        block_q=block_q, block_k=ps, sk=T * ps, kv_mask=None,
+        q_segment_ids=q_segment_ids, kv_segment_ids=kv_segment_ids,
+        q_positions=q_positions, kv_positions=kv_positions, dropout_seed=0,
+        interpret=interpret, causal=causal, window=window, q_offset=0,
+        kv_valid_len=None, dropout_p=0.0, q_len=sq, k_len=T * ps)
 
     if n_rep > 1:  # GQA group-sum in the packed layout
         dk_pk = dk_pk.reshape(b, hkv, n_rep, T * ps, d).sum(axis=2)

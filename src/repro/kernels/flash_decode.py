@@ -43,12 +43,14 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import masks as M
 from repro.core.masks import NEG_INF
 from repro.kernels import tuning
+from repro.kernels.ops import default_interpret
 from repro.kernels.flash_attention import LANES
 
 
-def _decode_kernel(kvl_ref, q_ref, k_ref, v_ref, lay_ref, kvm_ref,
+def _decode_kernel(kvl_ref, lay_ref, q_ref, k_ref, v_ref, kvm_ref,
                    o_ref, m_ref, l_ref, acc_sc, m_sc, l_sc, *,
-                   scale, block_k, window):
+                   scale, block_k, window, num_blocks):
+    b = pl.program_id(0)
     si, ki = pl.program_id(2), pl.program_id(3)   # split idx, block-in-split
     nk_in = pl.num_programs(3)
 
@@ -58,9 +60,10 @@ def _decode_kernel(kvl_ref, q_ref, k_ref, v_ref, lay_ref, kvm_ref,
         l_sc[...] = jnp.zeros_like(l_sc)
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
-    kv_len = kvl_ref[0]
+    # kv_len (b,) and the flattened (b, num_blocks) layout sit in SMEM
+    kv_len = kvl_ref[b]
     k0 = (si * nk_in + ki) * block_k
-    blk = lay_ref[0, 0]
+    blk = lay_ref[b * num_blocks + si * nk_in + ki]
 
     def _step(apply_mask):
         q = q_ref[0, 0].astype(jnp.float32)              # (1, d)
@@ -76,7 +79,7 @@ def _decode_kernel(kvl_ref, q_ref, k_ref, v_ref, lay_ref, kvm_ref,
             k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
             ok = M.element_mask(
                 kv_len - 1, k_pos, causal=True, window=window,
-                kv_valid=kvm_ref[0][None, :] if kvm_ref is not None else None)
+                kv_valid=kvm_ref[0, 0] != 0 if kvm_ref is not None else None)
             s = jnp.where(ok, s, NEG_INF)
 
         m_prev, l_prev = m_sc[:, 0], l_sc[:, 0]
@@ -94,9 +97,61 @@ def _decode_kernel(kvl_ref, q_ref, k_ref, v_ref, lay_ref, kvm_ref,
 
     @pl.when(ki == nk_in - 1)
     def _emit_partial():
-        o_ref[0, 0, 0] = acc_sc[0]        # unnormalized partial (d,)
-        m_ref[0, 0, 0] = m_sc[0, 0]
-        l_ref[0, 0, 0] = l_sc[0, 0]
+        o_ref[0, 0, 0] = acc_sc[...]      # unnormalized partial (1, d)
+        m_ref[0, 0, 0] = m_sc[...]        # lane-replicated (1, LANES)
+        l_ref[0, 0, 0] = l_sc[...]
+
+
+def _split_decode_call(kernel, *, grid, prefetch, q, k, v, kv_spec, kvm,
+                       block_k, interpret):
+    """The split-KV pallas_call shared by both cache geometries. Scalar
+    prefetch carries kv_len, the flattened block layout and (paged) the
+    page table; each split's partial state leaves as its own
+    (1, d) / (1, LANES) block of a (b, hq, splits, 1, ·) array — the
+    singleton axis keeps the block legal for any split count. Returns the
+    merged (b, hq, 1, d) output."""
+    b, hq, _, d = q.shape
+    num_splits = grid[2]
+    n_pre = len(prefetch)
+
+    in_specs = [pl.BlockSpec((1, 1, 1, d),
+                             lambda b, h, si, ki, *_: (b, h, 0, 0)),
+                kv_spec, kv_spec]
+    args = [q, k, v]
+    if kvm is not None:
+        nk_in = grid[3]
+        in_specs.append(pl.BlockSpec((1, 1, 1, block_k),
+                                     lambda b, h, si, ki, *_:
+                                     (b, si * nk_in + ki, 0, 0)))
+        args.append(kvm.astype(jnp.int32).reshape(b, -1, 1, block_k))
+
+    def wrapped(*refs):
+        kvl_ref, lay_ref = refs[n_pre - 2], refs[n_pre - 1]
+        q_ref, k_ref, v_ref, *rest = refs[n_pre:]
+        kvm_ref = rest.pop(0) if kvm is not None else None
+        return kernel(kvl_ref, lay_ref, q_ref, k_ref, v_ref, kvm_ref, *rest)
+
+    def part(width):
+        return pl.BlockSpec((1, 1, 1, 1, width),
+                            lambda b, h, si, ki, *_: (b, h, si, 0, 0))
+
+    o_p, m_p, l_p = pl.pallas_call(
+        wrapped,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=n_pre, grid=grid, in_specs=in_specs,
+            out_specs=[part(d), part(LANES), part(LANES)],
+            scratch_shapes=[pltpu.VMEM((1, d), jnp.float32),
+                            pltpu.VMEM((1, LANES), jnp.float32),
+                            pltpu.VMEM((1, LANES), jnp.float32)]),
+        out_shape=[
+            jax.ShapeDtypeStruct((b, hq, num_splits, 1, d), jnp.float32),
+            jax.ShapeDtypeStruct((b, hq, num_splits, 1, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((b, hq, num_splits, 1, LANES), jnp.float32),
+        ],
+        interpret=interpret,
+    )(*prefetch, *args)
+    return _merge_split_partials(o_p[:, :, :, 0], m_p[:, :, :, 0, 0],
+                                 l_p[:, :, :, 0, 0], q.dtype)
 
 
 def flash_decode(
@@ -132,7 +187,7 @@ def flash_decode(
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
 
     block_k, num_splits = tuning.resolve_decode_geometry(
         sk, block_k, num_splits, head_dim=d, dtype=k.dtype, shards=shards)
@@ -145,50 +200,14 @@ def flash_decode(
     layout = M.kv_block_layout(kv_valid, block_k).astype(jnp.int32)
 
     kernel = functools.partial(_decode_kernel, scale=scale, block_k=block_k,
-                               window=window)
-
-    in_specs = [
-        pl.BlockSpec((1,), lambda b, h, si, ki: (b,)),
-        pl.BlockSpec((1, 1, 1, d), lambda b, h, si, ki: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, block_k, d),
-                     lambda b, h, si, ki: (b, h // n_rep, si * nk_in + ki, 0)),
-        pl.BlockSpec((1, 1, block_k, d),
-                     lambda b, h, si, ki: (b, h // n_rep, si * nk_in + ki, 0)),
-        pl.BlockSpec((1, 1), lambda b, h, si, ki: (b, si * nk_in + ki)),
-    ]
-    args = [kv_len, q, k, v, layout]
-    if kvm is not None:
-        in_specs.append(
-            pl.BlockSpec((1, block_k), lambda b, h, si, ki: (b, si * nk_in + ki)))
-        args.append(kvm)
-
-    def wrapped(kvl_ref, q_ref, k_ref, v_ref, lay_ref, *rest):
-        kvm_ref, rest = (rest[0], rest[1:]) if kvm is not None else (None, rest)
-        return kernel(kvl_ref, q_ref, k_ref, v_ref, lay_ref, kvm_ref, *rest)
-
-    o_p, m_p, l_p = pl.pallas_call(
-        wrapped,
-        grid=(b, hq, num_splits, nk_in),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, 1, d), lambda b, h, si, ki: (b, h, si, 0)),
-            pl.BlockSpec((1, 1, 1), lambda b, h, si, ki: (b, h, si)),
-            pl.BlockSpec((1, 1, 1), lambda b, h, si, ki: (b, h, si)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, hq, num_splits, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, hq, num_splits), jnp.float32),
-            jax.ShapeDtypeStruct((b, hq, num_splits), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((1, d), jnp.float32),
-            pltpu.VMEM((1, LANES), jnp.float32),
-            pltpu.VMEM((1, LANES), jnp.float32),
-        ],
-        interpret=interpret,
-    )(*args)
-
-    return _merge_split_partials(o_p, m_p, l_p, q.dtype)
+                               window=window, num_blocks=num_splits * nk_in)
+    kv_spec = pl.BlockSpec(
+        (1, 1, block_k, d),
+        lambda b, h, si, ki, *_: (b, h // n_rep, si * nk_in + ki, 0))
+    return _split_decode_call(
+        kernel, grid=(b, hq, num_splits, nk_in),
+        prefetch=[kv_len, layout.reshape(-1)], q=q, k=k, v=v,
+        kv_spec=kv_spec, kvm=kvm, block_k=block_k, interpret=interpret)
 
 
 def validate_decode_geometry(capacity: int, block_k: int,
@@ -277,7 +296,7 @@ def flash_decode_paged(
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
 
     T = page_table.shape[1]
     if num_splits is None:
@@ -296,44 +315,14 @@ def flash_decode_paged(
     table = jnp.maximum(page_table, 0).astype(jnp.int32)
 
     kernel = functools.partial(_decode_kernel, scale=scale,
-                               block_k=page_size, window=window)
-
-    def wrapped(tab_ref, kvl_ref, q_ref, k_ref, v_ref, lay_ref, *rest):
-        return kernel(kvl_ref, q_ref, k_ref, v_ref, lay_ref, None, *rest)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, hq, num_splits, t_in),
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, h, si, ki, tab: (b,)),
-            pl.BlockSpec((1, 1, 1, d), lambda b, h, si, ki, tab: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, page_size, d),
-                         lambda b, h, si, ki, tab:
-                         (h // n_rep, tab[b, si * t_in + ki], 0, 0)),
-            pl.BlockSpec((1, 1, page_size, d),
-                         lambda b, h, si, ki, tab:
-                         (h // n_rep, tab[b, si * t_in + ki], 0, 0)),
-            pl.BlockSpec((1, 1), lambda b, h, si, ki, tab: (b, si * t_in + ki)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, 1, d), lambda b, h, si, ki, tab: (b, h, si, 0)),
-            pl.BlockSpec((1, 1, 1), lambda b, h, si, ki, tab: (b, h, si)),
-            pl.BlockSpec((1, 1, 1), lambda b, h, si, ki, tab: (b, h, si)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((1, d), jnp.float32),
-            pltpu.VMEM((1, LANES), jnp.float32),
-            pltpu.VMEM((1, LANES), jnp.float32),
-        ],
-    )
-    o_p, m_p, l_p = pl.pallas_call(
-        wrapped,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, hq, num_splits, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, hq, num_splits), jnp.float32),
-            jax.ShapeDtypeStruct((b, hq, num_splits), jnp.float32),
-        ],
-        interpret=interpret,
-    )(table, kv_len, q, k_pool, v_pool, layout)
-    return _merge_split_partials(o_p, m_p, l_p, q.dtype)
+                               block_k=page_size, window=window,
+                               num_blocks=T)
+    kv_spec = pl.BlockSpec(
+        (1, 1, page_size, d),
+        lambda b, h, si, ki, tab, *_: (h // n_rep, tab[b, si * t_in + ki],
+                                       0, 0))
+    return _split_decode_call(
+        kernel, grid=(b, hq, num_splits, t_in),
+        prefetch=[table, kv_len, layout.reshape(-1)], q=q, k=k_pool,
+        v=v_pool, kv_spec=kv_spec, kvm=None, block_k=page_size,
+        interpret=interpret)

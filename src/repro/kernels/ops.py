@@ -13,10 +13,11 @@ custom_vjp residuals, so the backward pass reuses the forward's compilation
 (including the once-per-batch segment min/max reduction) instead of
 re-deriving skip predicates per grid step.
 
-On this CPU container the kernels run with ``interpret=True`` (Pallas
-executes the kernel body op-by-op) — correctness-exact, wall-clock
-meaningless. On a real TPU set ``interpret=False`` (the default resolves via
-``repro.kernels.ops.default_interpret()``).
+``interpret`` left ``None`` resolves through ``default_interpret()``: on a
+TPU the kernels compile through Mosaic (``tpu_custom_call`` in the HLO); on
+any other backend — the CPU test suite — Pallas interprets the kernel body
+op by op, exact but with meaningless wall-clock. The low-level wrappers in
+``flash_attention`` take the resolved value and have no default.
 """
 
 from __future__ import annotations

@@ -404,21 +404,20 @@ def cache_key(device_kind: str, dtype: Any, head_dim: int, bucket: int,
 
 # Nominal HBM bandwidth per device kind, the denominator of the autotune
 # calibration factor (measured effective bytes/s over what the hardware
-# claims). Unknown kinds — CPU CI hosts included — fall back to a generic
-# DDR-class figure; the point of the factor is the RATIO trend per kind,
-# not an absolute roofline.
+# claims). v5e: 819 GB/s, Google Cloud documentation, "TPU v5e". A kind
+# with no row — CPU hosts included — has no nominal figure, so its
+# calibration factor is None ("not measured"), never a guessed ratio.
 _NOMINAL_HBM_BW: dict[str, float] = {
     "TPU v5 lite": io_model.V5E_HBM_BW,
     "TPU v5e": io_model.V5E_HBM_BW,
 }
-_FALLBACK_HBM_BW = 5e10
 
 
-def nominal_hbm_bw(device_kind: str) -> float:
+def nominal_hbm_bw(device_kind: str) -> float | None:
     for k, bw in _NOMINAL_HBM_BW.items():
         if k.lower() in device_kind.lower():
             return bw
-    return _FALLBACK_HBM_BW
+    return None
 
 
 class AutotuneCache:
@@ -496,15 +495,18 @@ class AutotuneCache:
         sample carried a model prediction yet. ``vs_nominal`` is the
         measured-vs-io_model factor: model-implied effective bandwidth
         over the kind's nominal bandwidth (1.0 = the analytic byte counts
-        at nominal speed explain the clock exactly)."""
+        at nominal speed explain the clock exactly); None for a kind with
+        no nominal bandwidth."""
         self._load()
         c = (self._calib or {}).get(device_kind)
         if not c or c["us"] <= 0:
             return None
         bytes_per_s = c["model_bytes"] / (c["us"] * 1e-6)
+        nominal = nominal_hbm_bw(device_kind)
         return {"samples": c["samples"],
                 "model_bytes_per_s": bytes_per_s,
-                "vs_nominal": bytes_per_s / nominal_hbm_bw(device_kind)}
+                "vs_nominal": (None if nominal is None
+                               else bytes_per_s / nominal)}
 
 
 _CACHE: AutotuneCache | None = None
@@ -973,10 +975,12 @@ def _main() -> None:
     kind = _device_kind()
     cal = cache.calibration(kind)
     if cal is not None:
+        ratio = ("not measured" if cal["vs_nominal"] is None else
+                 f"{cal['vs_nominal']:.3f}x nominal "
+                 f"({nominal_hbm_bw(kind) / 1e9:.0f} GB/s)")
         print(f"calibration[{kind}]: io_model-implied "
               f"{cal['model_bytes_per_s'] / 1e9:.2f} GB/s over "
-              f"{cal['samples']} timed samples = {cal['vs_nominal']:.3f}x "
-              f"nominal ({nominal_hbm_bw(kind) / 1e9:.0f} GB/s)")
+              f"{cal['samples']} timed samples = {ratio}")
     if args.expect_hit and not (hit and bwd_hit and dec_hit and sp_hit):
         raise SystemExit("expected a cache hit but resolution re-tuned "
                          f"(fwd={hit} bwd={bwd_hit} decode={dec_hit} "

@@ -6,8 +6,13 @@
     PYTHONPATH=src python -m repro.launch.serve --capacity 512 \
         --long-prompt 300 --chunk-size 64 --token-budget 80
 
-Reduced configs on CPU; on a TPU slice the same engine runs with the
-production mesh + `make_sharded_serve_steps` (sharded, donated decode).
+By default the engine serves the family-faithful reduced config (CPU
+tests and demos); ``--full`` serves the published config of ``--arch``
+(e.g. granite-3-2b at 40 layers, d_model 2048, 32q/8kv heads) on the
+accelerator, single-device or over the ``--tp``/``--sp`` mesh:
+
+    python -m repro.launch.serve --full --slots 8 --capacity 2048 \
+        --chunk-size 512 --requests 8 --max-new 32
 ``--dense`` selects the fixed-slot baseline cache; by default the engine
 pages (families with recurrent state fall back to dense automatically).
 ``--chunk-size`` splits prompt prefills into fixed-size chunks the
@@ -35,8 +40,9 @@ import time
 import jax
 import numpy as np
 
-from repro.configs import reduced_config
+from repro.configs import get_config, reduced_config
 from repro.kernels import tuning
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serve import ServingEngine
 
@@ -44,6 +50,9 @@ from repro.serve import ServingEngine
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3-2b")
+    ap.add_argument("--full", action="store_true",
+                    help="serve the published config of --arch instead of "
+                         "the reduced CPU-sized one")
     ap.add_argument("--autotune", action="store_true",
                     help="empirically time tile candidates on this device "
                          "(persisted in the autotune cache)")
@@ -133,10 +142,11 @@ def main():
         args.requests, args.max_new = 2, 5
         args.long_prompt, args.shared_prefix = 16, 8
 
+    enable_compile_cache()
     tuning.configure_tuning(sram_budget=args.sram_budget,
                             autotune=args.autotune or None)
-    cfg = reduced_config(args.arch)
-    if args.tp > 1 and cfg.num_kv_heads % args.tp:
+    cfg = get_config(args.arch) if args.full else reduced_config(args.arch)
+    if not args.full and args.tp > 1 and cfg.num_kv_heads % args.tp:
         # the reduced demo config may carry fewer kv heads than shards
         # (granite reduces to 4q/1kv); scale BOTH head counts, keeping the
         # GQA ratio, so every shard owns whole kv-head groups — the real

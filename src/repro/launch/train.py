@@ -30,6 +30,7 @@ from repro.configs import SHAPES, get_config, reduced_config
 from repro.data import SyntheticLM
 from repro.distributed.sharding import auto_rules, resolve_tree
 from repro.kernels import tuning
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.optim import adamw, warmup_cosine
 from repro.train import Trainer, TrainerConfig, make_sharded_train_step, make_train_step
@@ -57,6 +58,7 @@ def main():
                     help="full config on the production mesh (TPU slice)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     tuning.configure_tuning(sram_budget=args.sram_budget,
                             autotune=args.autotune or None)
     if args.reduced:

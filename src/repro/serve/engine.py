@@ -94,11 +94,6 @@ from repro.serve import sampling
 from repro.serve.scheduler import ChunkScheduler, ChunkTask, SchedulerConfig
 from repro.telemetry import IOLedger, ServePriceModel, Telemetry
 
-try:  # jax >= 0.4.30 module move
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # pragma: no cover - newer jax exposes jax.shard_map
-    from jax import shard_map  # type: ignore[attr-defined,no-redef]
-
 
 @dataclasses.dataclass
 class Request:
@@ -562,9 +557,9 @@ class ServingEngine:
         tokens, page tables, kv lengths, scatter indices, and logits are
         replicated (``P()``) — the host allocator's page indices are valid
         on every shard, and replicated logits make sampling a plain jit
-        with no collective. ``check_rep=False`` because the bodies psum at
-        the projection boundaries, which jax's replication checker cannot
-        see through in this jax version.
+        with no collective. ``check_vma=False``: the bodies psum at the
+        projection boundaries and return replicated values the
+        varying-manual-axes checker is not asked to prove.
 
         sp > 1 (DESIGN.md §14) changes ONLY the chunk-prefill call: its
         q-side batch rows (tokens / q_segment_ids / q_positions) shard
@@ -586,25 +581,25 @@ class ServingEngine:
         self._state_spec = state_spec
         sm = self._shard_model
 
-        self._decode_sm = shard_map(
+        self._decode_sm = jax.shard_map(
             sm.decode_step, mesh=mesh,
             in_specs=(self._param_specs, state_spec, P()),
-            out_specs=(state_spec, P()), check_rep=False)
+            out_specs=(state_spec, P()), check_vma=False)
         self._decode = jax.jit(self._decode_sm, donate_argnums=(1,))
         if self.sp == 1:
             packed_spec = jax.tree.map(
                 lambda _: P(None, None, "tp", None, None),
                 self.state["caches"])
-            self._scatter_sm = shard_map(
+            self._scatter_sm = jax.shard_map(
                 kvc.scatter_packed_segments, mesh=mesh,
                 in_specs=(pool_spec, packed_spec, P(), P()),
-                out_specs=pool_spec, check_rep=False)
+                out_specs=pool_spec, check_vma=False)
             self._scatter = jax.jit(self._scatter_sm, donate_argnums=(0,))
-            self._prefill_packed_sm = shard_map(
+            self._prefill_packed_sm = jax.shard_map(
                 sm.prefill_packed, mesh=mesh,
                 in_specs=(self._param_specs,
                           {"tokens": P(), "segment_ids": P()}),
-                out_specs=(packed_spec, P()), check_rep=False)
+                out_specs=(packed_spec, P()), check_vma=False)
             self._prefill_packed = jax.jit(self._prefill_packed_sm)
         q_spec = P(None, "sp") if self.sp > 1 else P()
         logits_spec = P(None, "sp", None) if self.sp > 1 else P()
@@ -613,10 +608,10 @@ class ServingEngine:
             "kv_segment_ids": P(), "kv_positions": P(),
             "dest_page": P(), "dest_off": P(), "page_list": P()}
         self._chunk_batch_spec = chunk_batch_spec
-        self._prefill_chunk_sm = shard_map(
+        self._prefill_chunk_sm = jax.shard_map(
             sm.prefill_chunk_paged, mesh=mesh,
             in_specs=(self._param_specs, chunk_batch_spec, pool_spec),
-            out_specs=(pool_spec, logits_spec), check_rep=False)
+            out_specs=(pool_spec, logits_spec), check_vma=False)
         self._prefill_chunk = jax.jit(self._prefill_chunk_sm,
                                       donate_argnums=(2,))
         # shard the freshly built (zero) pool in place; table/len replicated

@@ -22,19 +22,8 @@ def run_devices(body: str, n_devices: int = 8, timeout: int = 420) -> str:
     script = textwrap.dedent(f"""
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={n_devices}"
-        import inspect
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P, NamedSharding
-        # jax < 0.5 compat: AxisType / make_mesh(axis_types=...) landed
-        # later; older versions build Auto meshes by default.
-        if not hasattr(jax.sharding, "AxisType"):
-            class _AxisType:
-                Auto = None
-            jax.sharding.AxisType = _AxisType
-        if "axis_types" not in inspect.signature(jax.make_mesh).parameters:
-            _make_mesh = jax.make_mesh
-            jax.make_mesh = (lambda shape, names, **kw:
-                             _make_mesh(shape, names))
     """) + textwrap.dedent(body)
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
@@ -254,7 +243,6 @@ def test_elastic_restore_across_meshes(tmp_path):
 
 def test_compressed_mean_matches_exact_mean():
     body = """
-    from jax.experimental.shard_map import shard_map
     from repro.distributed.compression import compressed_mean_tree
     mesh = jax.make_mesh((8,), ("data",),
                          axis_types=(jax.sharding.AxisType.Auto,))
@@ -265,8 +253,8 @@ def test_compressed_mean_matches_exact_mean():
         out = compressed_mean_tree({"g": g[0]}, "data")
         return out["g"][None]
 
-    fn = shard_map(body_fn, mesh=mesh, in_specs=P("data", None),
-                   out_specs=P("data", None), check_rep=False)
+    fn = jax.shard_map(body_fn, mesh=mesh, in_specs=P("data", None),
+                       out_specs=P("data", None), check_vma=False)
     approx = np.asarray(fn(g_global))[0]
     exact = np.asarray(g_global.mean(axis=0))
     # int8 per-tensor quantization: ~1% of max error

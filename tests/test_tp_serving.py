@@ -107,6 +107,20 @@ def test_decode_census_psum_only(setup):
 
 
 @pytest.mark.multidevice
+@pytest.mark.parametrize("check_vma", [False, True])
+def test_census_names_psum_under_either_vma_setting(check_vma):
+    """``jax.shard_map(check_vma=True)`` traces a psum as
+    ``psum_invariant``; the census counts it as ``psum`` either way."""
+    from repro.distributed.sharding import collective_census
+    mesh = Mesh(np.asarray(jax.devices()[:2]), ("tp",))
+    step = jax.shard_map(lambda x: jax.lax.psum(x, "tp"), mesh=mesh,
+                         in_specs=P("tp"), out_specs=P(),
+                         check_vma=check_vma)
+    jaxpr = jax.make_jaxpr(step)(jnp.ones((2, 4), jnp.float32))
+    assert collective_census(jaxpr) == {"psum": 1}
+
+
+@pytest.mark.multidevice
 def test_prefill_census_per_step_kind(setup):
     """The census contract extends to every PREFILL step function: the
     packed zero-offset prefill and the paged chunk step each carry

@@ -252,3 +252,22 @@ class TestAutotuneCache:
         finally:
             tuning.configure_tuning(cache_path=tuning._DEFAULT_CACHE,
                                     autotune=False)
+
+
+@pytest.mark.parametrize("kind,bw", [("TPU v5 lite", io_model.V5E_HBM_BW),
+                                     ("TPU v5e", io_model.V5E_HBM_BW),
+                                     ("cpu", None), ("TPU v4", None)])
+def test_nominal_hbm_bw_only_for_known_kinds(kind, bw):
+    """A device kind with no row gets no nominal bandwidth (and so no
+    calibration ratio), never a guessed default."""
+    assert tuning.nominal_hbm_bw(kind) == bw
+
+
+def test_calibration_ratio_not_measured_for_unknown_kind(tmp_path):
+    cache = tuning.AutotuneCache(str(tmp_path / "c.json"))
+    cfg = tuning.TileConfig(block_q=64, block_k=64, source="autotuned")
+    for kind in ("cpu", "TPU v5 lite"):
+        cache.put(f"{kind}|k", cfg, 10.0, model_hbm_bytes=8.19e6,
+                  device_kind=kind)
+    assert cache.calibration("cpu")["vs_nominal"] is None
+    assert cache.calibration("TPU v5 lite")["vs_nominal"] == pytest.approx(1.0)
