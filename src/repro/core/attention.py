@@ -25,7 +25,10 @@ Both paths derive key validity from ``masks.decode_kv_valid`` (kv_len +
 window + optional slot mask) and mask with the shared NEG_INF sentinel.
 
 Implementations are numerically interchangeable (tests assert pairwise
-agreement) — exactness is the paper's core claim.
+agreement) — exactness is the paper's core claim. Each entry point traces
+under the ``attention`` named scope (``telemetry/scopes.py``), whatever
+implementation it dispatches to, so a profile splits attention's device
+time from the rest of a step program.
 """
 
 from __future__ import annotations
@@ -40,6 +43,7 @@ from repro.core import masks
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 from repro.kernels.flash_decode import flash_decode, flash_decode_paged
+from repro.telemetry.scopes import ATTENTION, scoped
 
 AttnImpl = Literal["pallas", "chunked", "reference", "block_sparse"]
 
@@ -74,6 +78,7 @@ class AttentionSpec:
                                     # seen here are then per-shard)
 
 
+@scoped(ATTENTION)
 def attention(
     q: jax.Array, k: jax.Array, v: jax.Array,
     spec: AttentionSpec,
@@ -135,6 +140,7 @@ def attention(
     raise ValueError(f"unknown attention impl {spec.impl!r}")
 
 
+@scoped(ATTENTION)
 def decode_attention(
     q: jax.Array,            # (b, hq, 1, d)
     k_cache: jax.Array,      # (b, hkv, capacity, d)
@@ -183,6 +189,7 @@ def decode_attention(
     return o.reshape(b, hq, sq, d).astype(q.dtype)
 
 
+@scoped(ATTENTION)
 def paged_prefill_attention(
     q: jax.Array,            # (b, hq, sq, d) — the suffix chunk's queries
     k_pool: jax.Array,       # (hkv, num_pages, page_size, d) — shared pool
@@ -234,6 +241,7 @@ def paged_prefill_attention(
         q_positions=q_positions, kv_positions=kv_positions, scale=scale)
 
 
+@scoped(ATTENTION)
 def paged_decode_attention(
     q: jax.Array,            # (b, hq, 1, d)
     k_pool: jax.Array,       # (hkv, num_pages, page_size, d) — shared pool

@@ -57,6 +57,7 @@ from repro.core.io_model import LANES  # noqa: F401 — one source of truth:
 # accounts the lane-replicated m/l scratch with the SAME constant the
 # kernels allocate it with; flash_decode re-imports it from here.
 from repro.core.masks import NEG_INF
+from repro.telemetry import scopes
 
 
 # ---------------------------------------------------------------------------
@@ -319,13 +320,13 @@ def _split_opts(rest, has_kvm, has_seg, has_pos=False):
     return kvm_ref, qseg_ref, kseg_ref, qpos_ref, kpos_ref, rest
 
 
-def _flash_call(kernel, *, n_fixed, grid, block_layout, dropout_seed,
+def _flash_call(kernel, *, name, n_fixed, grid, block_layout, dropout_seed,
                 table, in_specs, args, rows, out_specs, out_shape, scratch,
                 has_kvm, has_seg, has_pos, interpret):
-    """One fwd/dq/dkv pallas_call. ``kernel`` takes (lay_ref, seed_ref,
-    *n_fixed refs, kvm, qseg, kseg, qpos, kpos, *outputs, *scratch), with
-    None for the absent mask rows; ``table`` (b, T) is the page table of
-    a paged call, else None."""
+    """One fwd/dq/dkv pallas_call named ``name``. ``kernel`` takes
+    (lay_ref, seed_ref, *n_fixed refs, kvm, qseg, kseg, qpos, kpos,
+    *outputs, *scratch), with None for the absent mask rows; ``table``
+    (b, T) is the page table of a paged call, else None."""
     prefetch = [jnp.asarray(block_layout, jnp.int32).reshape(-1),
                 jnp.asarray(dropout_seed, jnp.uint32).reshape(1)]
     if table is not None:
@@ -349,6 +350,7 @@ def _flash_call(kernel, *, n_fixed, grid, block_layout, dropout_seed,
             scratch_shapes=scratch),
         out_shape=out_shape,
         interpret=interpret,
+        name=name,
     )(*prefetch, *args, *rows[1])
 
 
@@ -366,6 +368,8 @@ def _forward(q, k, v, block_layout, table, *, block_q, block_k, sk,
     return _flash_call(
         functools.partial(_fwd_kernel, layout_shape=block_layout.shape,
                           num_heads=hq, **static),
+        name=(scopes.FLASH_FWD if table is None
+              else scopes.FLASH_PREFILL_PAGED_FWD),
         n_fixed=3, grid=(b, hq, sq // block_q, sk // block_k),
         block_layout=block_layout, dropout_seed=dropout_seed, table=table,
         in_specs=[q_spec, kv_spec, kv_spec], args=[q, k, v],
@@ -607,6 +611,7 @@ def _backward(q, k, v, o, do, m, l, block_layout, table, *, scale, block_q,
     # ---- dq kernel: grid over q blocks, kv innermost ----
     dq = _flash_call(
         functools.partial(_dq_kernel, scale=scale, **kernel_static),
+        name=scopes.FLASH_PREFILL_PAGED_DQ if paged else scopes.FLASH_DQ,
         n_fixed=7, grid=(b, hq, nq, nk), in_specs=in_specs(False),
         rows=_mask_rows(b, block_q, block_k, **rows),
         out_specs=_q_row_spec(block_q, d),
@@ -618,6 +623,7 @@ def _backward(q, k, v, o, do, m, l, block_layout, table, *, scale, block_q,
                    lambda b, h, qi, ki, *_: (b, h, ki, 0), q_inner=True)
     dk_p, dv_p = _flash_call(
         functools.partial(_dkv_kernel, **kernel_static),
+        name=scopes.FLASH_PREFILL_PAGED_DKV if paged else scopes.FLASH_DKV,
         n_fixed=7, grid=(b, hq, nk, nq), in_specs=in_specs(True),
         rows=_mask_rows(b, block_q, block_k, q_inner=True, **rows),
         out_specs=[kv_out, kv_out],

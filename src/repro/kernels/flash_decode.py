@@ -45,6 +45,7 @@ from repro.core.masks import NEG_INF
 from repro.kernels import tuning
 from repro.kernels.ops import default_interpret
 from repro.kernels.flash_attention import LANES
+from repro.telemetry import scopes
 
 
 def _decode_kernel(kvl_ref, lay_ref, q_ref, k_ref, v_ref, kvm_ref,
@@ -102,9 +103,10 @@ def _decode_kernel(kvl_ref, lay_ref, q_ref, k_ref, v_ref, kvm_ref,
         l_ref[0, 0, 0] = l_sc[...]
 
 
-def _split_decode_call(kernel, *, grid, prefetch, q, k, v, kv_spec, kvm,
-                       block_k, interpret):
-    """The split-KV pallas_call shared by both cache geometries. Scalar
+def _split_decode_call(kernel, *, name, grid, prefetch, q, k, v, kv_spec,
+                       kvm, block_k, interpret):
+    """The split-KV pallas_call, named ``name``, shared by both cache
+    geometries. Scalar
     prefetch carries kv_len, the flattened block layout and (paged) the
     page table; each split's partial state leaves as its own
     (1, d) / (1, LANES) block of a (b, hq, splits, 1, ·) array — the
@@ -149,6 +151,7 @@ def _split_decode_call(kernel, *, grid, prefetch, q, k, v, kv_spec, kvm,
             jax.ShapeDtypeStruct((b, hq, num_splits, 1, LANES), jnp.float32),
         ],
         interpret=interpret,
+        name=name,
     )(*prefetch, *args)
     return _merge_split_partials(o_p[:, :, :, 0], m_p[:, :, :, 0, 0],
                                  l_p[:, :, :, 0, 0], q.dtype)
@@ -205,7 +208,7 @@ def flash_decode(
         (1, 1, block_k, d),
         lambda b, h, si, ki, *_: (b, h // n_rep, si * nk_in + ki, 0))
     return _split_decode_call(
-        kernel, grid=(b, hq, num_splits, nk_in),
+        kernel, name=scopes.FLASH_DECODE, grid=(b, hq, num_splits, nk_in),
         prefetch=[kv_len, layout.reshape(-1)], q=q, k=k, v=v,
         kv_spec=kv_spec, kvm=kvm, block_k=block_k, interpret=interpret)
 
@@ -322,7 +325,8 @@ def flash_decode_paged(
         lambda b, h, si, ki, tab, *_: (h // n_rep, tab[b, si * t_in + ki],
                                        0, 0))
     return _split_decode_call(
-        kernel, grid=(b, hq, num_splits, t_in),
+        kernel, name=scopes.FLASH_DECODE_PAGED,
+        grid=(b, hq, num_splits, t_in),
         prefetch=[table, kv_len, layout.reshape(-1)], q=q, k=k_pool,
         v=v_pool, kv_spec=kv_spec, kvm=None, block_k=page_size,
         interpret=interpret)

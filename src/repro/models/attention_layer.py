@@ -20,6 +20,7 @@ from repro.core.attention import (AttentionSpec, attention, decode_attention,
                                   paged_prefill_attention)
 from repro.core.masks import segment_relative_positions
 from repro.models.layers import apply_rope, dense_init, rms_normalize
+from repro.telemetry import scopes
 
 
 def attn_spec_from_config(cfg: ModelConfig) -> AttentionSpec:
@@ -338,7 +339,8 @@ def chunk_prefill_attention_step(params, cfg: ModelConfig, x, pool,
         return c.at[:, dest_page, dest_off, :].set(new[0].astype(c.dtype),
                                                    mode="drop")
 
-    pool = {"k": _scat(pool["k"], k), "v": _scat(pool["v"], v)}
+    with scopes.named(scopes.KV_WRITE):
+        pool = {"k": _scat(pool["k"], k), "v": _scat(pool["v"], v)}
     spec = spec or attn_spec_from_config(cfg)
     o = paged_prefill_attention(q, pool["k"], pool["v"], page_list, spec,
                                 q_segment_ids=q_seg, kv_segment_ids=kv_seg,
@@ -380,7 +382,8 @@ def paged_decode_attention_step(params, cfg: ModelConfig, x, pool,
         rows = new[:, :, 0].transpose(1, 0, 2).astype(c.dtype)  # (hkv, b, hd)
         return c.at[:, phys, off, :].set(rows, mode="drop")
 
-    pool = {"k": _upd(pool["k"], k), "v": _upd(pool["v"], v)}
+    with scopes.named(scopes.KV_WRITE):
+        pool = {"k": _upd(pool["k"], k), "v": _upd(pool["v"], v)}
     spec = spec or attn_spec_from_config(cfg)
     o = paged_decode_attention(q, pool["k"], pool["v"], page_table,
                                kv_len + 1, spec)

@@ -26,6 +26,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.models import transformer as tfm
 from repro.models.layers import apply_norm, dense_init, init_norm, norm_specs
+from repro.telemetry import scopes
 
 Params = dict[str, Any]
 
@@ -79,6 +80,7 @@ class Model:
         return s
 
     # --------------------------------------------------------------- forward
+    @scopes.scoped(scopes.LOGITS)
     def _logits(self, params, h):
         h = apply_norm(params["final_norm"], h, self.cfg.norm_type)
         head = params.get("lm_head", None)
@@ -135,16 +137,19 @@ class Model:
         n_front = cfg.frontend_tokens if cfg.frontend == "vision" else 0
         if n_front:
             logits = logits[:, n_front:]
-        # next-token prediction
-        logits = logits[:, :-1].astype(jnp.float32)
-        targets = tokens[:, 1:]
-        mask = batch.get("loss_mask", jnp.ones_like(tokens, jnp.float32))[:, 1:]
-        logz = jax.scipy.special.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-        nll = (logz - gold) * mask
-        denom = jnp.maximum(mask.sum(), 1.0)
-        ce = nll.sum() / denom
-        total = ce + aux_weight * aux
+        with scopes.named(scopes.LOSS):
+            # next-token prediction
+            logits = logits[:, :-1].astype(jnp.float32)
+            targets = tokens[:, 1:]
+            mask = batch.get("loss_mask",
+                             jnp.ones_like(tokens, jnp.float32))[:, 1:]
+            logz = jax.scipy.special.logsumexp(logits, axis=-1)
+            gold = jnp.take_along_axis(logits, targets[..., None],
+                                       axis=-1)[..., 0]
+            nll = (logz - gold) * mask
+            denom = jnp.maximum(mask.sum(), 1.0)
+            ce = nll.sum() / denom
+            total = ce + aux_weight * aux
         return total, {"loss": total, "ce": ce, "aux": aux,
                        "tokens": denom}
 

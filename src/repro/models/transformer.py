@@ -30,6 +30,7 @@ from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
 from repro.models.layers import (apply_mlp, apply_norm, init_mlp, init_norm,
                                  mlp_specs, norm_specs, rms_normalize)
+from repro.telemetry import scopes
 
 Params = dict[str, Any]
 
@@ -79,6 +80,23 @@ def block_specs(cfg: ModelConfig, *, cross_attn: bool = False):
     return s
 
 
+def apply_ffn(params: Params, cfg: ModelConfig, x):
+    """The block's pre-norm feed-forward sub-layer (dense MLP or MoE) with
+    its residual, under the ``mlp`` scope. Returns (x, aux_loss); a block
+    without one (d_ff 0) returns x unchanged."""
+    aux = jnp.float32(0.0)
+    if "moe" not in params and "mlp" not in params:
+        return x, aux
+    with scopes.named(scopes.MLP):
+        h = apply_norm(params["mlp_norm"], x, cfg.norm_type)
+        if "moe" in params:
+            y, aux = moe_mod.apply_moe(params["moe"], cfg, h)
+        else:
+            y = attn_mod._tp_reduce(
+                apply_mlp(params["mlp"], h, cfg.mlp_type), cfg)
+    return x + y, aux
+
+
 def apply_block(params: Params, cfg: ModelConfig, x, *,
                 enc_out=None, enc_mask=None, segment_ids=None,
                 deterministic=True, dropout_seed=0,
@@ -90,7 +108,6 @@ def apply_block(params: Params, cfg: ModelConfig, x, *,
     and do NOT reset state at boundaries — packing is an attention-family
     feature (DESIGN.md §8).
     """
-    aux = jnp.float32(0.0)
     if cfg.sp_activations and x.ndim == 3:
         # sequence-parallel residual stream (§Perf lever): shard the seq dim
         # over the model axis between blocks, so norms/elementwise run on
@@ -133,15 +150,7 @@ def apply_block(params: Params, cfg: ModelConfig, x, *,
                                          kv_x=enc_out, kv_mask=enc_mask,
                                          deterministic=deterministic)
 
-    if "moe" in params:
-        h = apply_norm(params["mlp_norm"], x, cfg.norm_type)
-        y, aux = moe_mod.apply_moe(params["moe"], cfg, h)
-        x = x + y
-    elif "mlp" in params:
-        h = apply_norm(params["mlp_norm"], x, cfg.norm_type)
-        x = x + attn_mod._tp_reduce(
-            apply_mlp(params["mlp"], h, cfg.mlp_type), cfg)
-    return x, aux
+    return apply_ffn(params, cfg, x)
 
 
 # ---------------------------------------------------------------------------
@@ -271,14 +280,7 @@ def apply_block_decode(params: Params, cfg: ModelConfig, x, cache, kv_len,
         x = x + o @ params["cross_attn"]["wo"]
         new_cache["cross_kv"] = ck
 
-    if "moe" in params:
-        h = apply_norm(params["mlp_norm"], x, cfg.norm_type)
-        y, _ = moe_mod.apply_moe(params["moe"], cfg, h)
-        x = x + y
-    elif "mlp" in params:
-        h = apply_norm(params["mlp_norm"], x, cfg.norm_type)
-        x = x + attn_mod._tp_reduce(
-            apply_mlp(params["mlp"], h, cfg.mlp_type), cfg)
+    x, _ = apply_ffn(params, cfg, x)
     return x, new_cache
 
 
@@ -313,14 +315,7 @@ def apply_block_decode_paged(params: Params, cfg: ModelConfig, x, cache,
     a, kv = attn_mod.paged_decode_attention_step(
         params["attn"], cfg, h, cache["kv"], page_table, kv_len)
     x = x + a
-    if "moe" in params:
-        h = apply_norm(params["mlp_norm"], x, cfg.norm_type)
-        y, _ = moe_mod.apply_moe(params["moe"], cfg, h)
-        x = x + y
-    elif "mlp" in params:
-        h = apply_norm(params["mlp_norm"], x, cfg.norm_type)
-        x = x + attn_mod._tp_reduce(
-            apply_mlp(params["mlp"], h, cfg.mlp_type), cfg)
+    x, _ = apply_ffn(params, cfg, x)
     return x, {"kv": kv}
 
 
@@ -335,14 +330,7 @@ def apply_block_chunk_prefill(params: Params, cfg: ModelConfig, x, cache,
         params["attn"], cfg, h, cache["kv"], dest_page, dest_off,
         page_list, q_seg, kv_seg, q_pos, kv_pos)
     x = x + a
-    if "moe" in params:
-        h = apply_norm(params["mlp_norm"], x, cfg.norm_type)
-        y, _ = moe_mod.apply_moe(params["moe"], cfg, h)
-        x = x + y
-    elif "mlp" in params:
-        h = apply_norm(params["mlp_norm"], x, cfg.norm_type)
-        x = x + attn_mod._tp_reduce(
-            apply_mlp(params["mlp"], h, cfg.mlp_type), cfg)
+    x, _ = apply_ffn(params, cfg, x)
     return x, {"kv": kv}
 
 
@@ -484,14 +472,7 @@ def apply_block_prefill(params: Params, cfg: ModelConfig, x, capacity: int,
             ck = rms_normalize(ck) * params["cross_attn"]["k_norm"]
         cache_l["cross_kv"] = {"k": ck.astype(dtype), "v": cv.astype(dtype)}
 
-    if "moe" in params:
-        h = apply_norm(params["mlp_norm"], x, cfg.norm_type)
-        y, _ = moe_mod.apply_moe(params["moe"], cfg, h)
-        x = x + y
-    elif "mlp" in params:
-        h = apply_norm(params["mlp_norm"], x, cfg.norm_type)
-        x = x + attn_mod._tp_reduce(
-            apply_mlp(params["mlp"], h, cfg.mlp_type), cfg)
+    x, _ = apply_ffn(params, cfg, x)
     return x, cache_l
 
 

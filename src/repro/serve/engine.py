@@ -104,11 +104,13 @@ class Request:
         default_factory=sampling.SamplingParams)
     output: list[int] = dataclasses.field(default_factory=list)
     done: bool = False
-    # latency observability: submit wall-clock and first-generated-token
+    # latency observability: submit wall-clock, first-generated-token
     # wall-clock (None until the first chunk of prefill completes; survives
-    # preempt->resume — the FIRST emission is the TTFT).
+    # preempt->resume — the FIRST emission is the TTFT) and the wall-clock
+    # of the latest token (the start of the next inter-token gap).
     t_submit: float = 0.0
     t_first: float | None = None
+    t_last: float | None = None
 
     @property
     def resume_tokens(self) -> list[int]:
@@ -177,7 +179,8 @@ class ServingEngine:
         self._h_ttft = reg.histogram(
             "serve_ttft_s", "submit -> first generated token (s)")
         self._h_tok = reg.histogram(
-            "serve_tok_latency_s", "per-token decode step latency (s)")
+            "serve_tok_latency_s",
+            "gap between successive tokens of one request (s)")
         self._stepped = False
         self._step_idx = 0
         self._preempted_rids: set[int] = set()
@@ -465,6 +468,13 @@ class ServingEngine:
             block_q=rep.block_q, block_k=rep.block_k, kv_major=rep.kv_major,
             tp=self.tp, sp=self.sp,
             sp_strategy=self.sp_strategy or "replicated"))
+        tr = self.tm.tracer
+        if tr.enabled:
+            # constant for the engine's lifetime: recorded once, not per span
+            tr.event("meta", "engine", census={
+                k: self._declared_census(k) for k in
+                ("prefill_zero", "prefill_chunk", "prefill_dense", "decode")},
+                tiles=self._tile_args())
 
     # --------------------- back-compat views over the telemetry registry
     @property
@@ -526,7 +536,7 @@ class ServingEngine:
 
     @property
     def tok_latencies(self) -> list[float]:
-        """Raw per-token decode latency samples — histogram-backed view."""
+        """Raw inter-token gap samples — histogram-backed view."""
         return self._h_tok.samples()
 
     @property
@@ -784,10 +794,11 @@ class ServingEngine:
                            np.float32)
         tops = np.asarray([r.params.top_p if r else 1.0 for r in reqs],
                           np.float32)
-        return np.asarray(self._sample(logits_rows, jnp.asarray(seeds),
-                                       jnp.asarray(counts),
-                                       jnp.asarray(temps),
-                                       jnp.asarray(tops)), np.int32)
+        toks = self._sample(logits_rows, jnp.asarray(seeds),
+                            jnp.asarray(counts), jnp.asarray(temps),
+                            jnp.asarray(tops))
+        with self.tm.tracer.scope("engine.sync"):
+            return np.asarray(toks, np.int32)
 
     # ------------------------------------------------------------- bookkeeping
     def _publish_prefix(self, req: Request, n_rows: int) -> None:
@@ -817,16 +828,26 @@ class ServingEngine:
             self._kv_len_h[lane] = 0
             self._paged_dirty = True
 
+    def _append_token(self, req: Request, tok: int, now: float) -> None:
+        """Hand one generated token to the request at host time ``now``:
+        the gap since its previous token is what its stream sees, prefill
+        chunks and preemptions in between included."""
+        req.output.append(tok)
+        if req.t_last is not None:
+            self._h_tok.observe(now - req.t_last)
+        req.t_last = now
+
     def _post_prefill(self, lane: int, req: Request, tok: int) -> None:
         """The final chunk's logits produced the first generated token."""
+        now = time.perf_counter()
         if req.t_first is None:
-            req.t_first = time.perf_counter()
+            req.t_first = now
             self._h_ttft.observe(req.t_first - req.t_submit)
             tr = self.tm.tracer
             if tr.enabled:
                 tr.event("req", "first_token", rid=req.rid,
                          ttft_s=req.t_first - req.t_submit)
-        req.output.append(tok)
+        self._append_token(req, tok, now)
         hit_eos = self.eos_id is not None and tok == self.eos_id
         if hit_eos or len(req.output) >= req.max_new_tokens:
             self._finish(lane, req,
@@ -892,29 +913,32 @@ class ServingEngine:
         themselves, so they run as ONE packed self-attention prefill (the
         historical path) scattered straight into pool pages."""
         t_w = time.perf_counter()
-        reqs = [self.requests[t.rid] for t in tasks]
-        lengths = [t.length for t in tasks]
-        toks, segs, offsets = self._packed_batch(reqs, lengths)
-        caches, logits = self._prefill_packed(
-            self.params, {"tokens": jnp.asarray(toks),
-                          "segment_ids": jnp.asarray(segs)})
+        tr = self.tm.tracer
+        with tr.scope("engine.prefill.build"):
+            reqs = [self.requests[t.rid] for t in tasks]
+            lengths = [t.length for t in tasks]
+            toks, segs, offsets = self._packed_batch(reqs, lengths)
+            tables = [self.kv.table(t.rid) for t in tasks]
+            dest_page, dest_off = kvc.packed_destinations(
+                tables, offsets, lengths, self.page_size, toks.shape[1],
+                self.kv.num_pages)
+            batch = {"tokens": jnp.asarray(toks),
+                     "segment_ids": jnp.asarray(segs)}
+            dest_page, dest_off = jnp.asarray(dest_page), jnp.asarray(dest_off)
+        with tr.scope("engine.prefill.launch"):
+            caches, logits = self._prefill_packed(self.params, batch)
+            self.state["caches"] = self._scatter(
+                self.state["caches"], caches, dest_page, dest_off)
         self._c_prefill_calls.inc()
         self._record_layout_stats(segs)
-        tables = [self.kv.table(t.rid) for t in tasks]
-        total = toks.shape[1]
-        dest_page, dest_off = kvc.packed_destinations(
-            tables, offsets, lengths, self.page_size, total,
-            self.kv.num_pages)
-        self.state["caches"] = self._scatter(
-            self.state["caches"], caches, jnp.asarray(dest_page),
-            jnp.asarray(dest_off))
         self._paged_dirty = True
         for i, t in enumerate(tasks):
             self._kv_len_h[t.lane] = t.length
             self._publish_prefix(reqs[i], t.length)
         self._emit_first_tokens(tasks, logits, offsets)
-        self._account_prefill("prefill_zero", tasks,
-                              time.perf_counter() - t_w)
+        with tr.scope("engine.account"):
+            self._account_prefill("prefill_zero", tasks,
+                                  time.perf_counter() - t_w)
 
     def _emit_first_tokens(self, tasks, logits, offsets) -> None:
         """Sample the first generated token of every task whose chunk
@@ -949,50 +973,47 @@ class ServingEngine:
         path (counted in ``prefill_gather_bytes_eliminated``).
         """
         t_w = time.perf_counter()
-        reqs = [self.requests[t.rid] for t in tasks]
-        lengths = [t.length for t in tasks]
-        starts = [t.start for t in tasks]
-        q_off = np.concatenate([[0], np.cumsum(lengths)])
-        total_q = int(q_off[-1])
-        Sq = total_q + (-total_q) % self.prefill_bucket
-        toks = np.zeros((1, Sq), np.int32)
-        qseg = np.full((1, Sq), SEG_PAD_Q, np.int32)
-        qpos = np.full((1, Sq), POS_PAD, np.int32)
-        for i, (r, st, n) in enumerate(zip(reqs, starts, lengths)):
-            sl = slice(int(q_off[i]), int(q_off[i + 1]))
-            toks[0, sl] = r.resume_tokens[st:st + n]
-            qseg[0, sl] = i
-            qpos[0, sl] = np.arange(st, st + n)
+        tr = self.tm.tracer
+        with tr.scope("engine.prefill.build"):
+            reqs = [self.requests[t.rid] for t in tasks]
+            lengths = [t.length for t in tasks]
+            starts = [t.start for t in tasks]
+            q_off = np.concatenate([[0], np.cumsum(lengths)])
+            total_q = int(q_off[-1])
+            Sq = total_q + (-total_q) % self.prefill_bucket
+            toks = np.zeros((1, Sq), np.int32)
+            qseg = np.full((1, Sq), SEG_PAD_Q, np.int32)
+            qpos = np.full((1, Sq), POS_PAD, np.int32)
+            for i, (r, st, n) in enumerate(zip(reqs, starts, lengths)):
+                sl = slice(int(q_off[i]), int(q_off[i + 1]))
+                toks[0, sl] = r.resume_tokens[st:st + n]
+                qseg[0, sl] = i
+                qpos[0, sl] = np.arange(st, st + n)
 
-        spans = [st + n for st, n in zip(starts, lengths)]
-        tables = [self.kv.table(t.rid) for t in tasks]
-        dest_page, dest_off = kvc.chunk_destinations(
-            tables, starts, q_off, lengths, self.page_size, Sq,
-            self.kv.num_pages)
-        # page-aligned kv packing: segment i's prefix occupies its own
-        # whole page slots, so the packed width is pages * page_size,
-        # bucketed (the bucket is a page multiple by construction).
-        pages_needed = sum(kvc.pages_for(sp, self.page_size) for sp in spans)
-        Sk = self._kv_bucketed(pages_needed * self.page_size)
-        page_list, kseg, kpos = kvc.paged_prefix_lists(
-            tables, spans, self.page_size, Sk // self.page_size)
-        cfg = self.model.cfg
-        self._c_gather_elim.inc(int(sum(
-            io_model.gather_hbm_bytes(sp, cfg.head_dim, cfg.num_kv_heads,
-                                      elt=tuning._elt_bytes(cfg.dtype),
-                                      layers=cfg.num_layers)
-            for sp in spans)))
-
-        batch = {"tokens": jnp.asarray(toks),
-                 "q_segment_ids": jnp.asarray(qseg),
-                 "q_positions": jnp.asarray(qpos),
-                 "kv_segment_ids": jnp.asarray(kseg[None]),
-                 "kv_positions": jnp.asarray(kpos[None]),
-                 "dest_page": jnp.asarray(dest_page),
-                 "dest_off": jnp.asarray(dest_off),
-                 "page_list": jnp.asarray(page_list[None])}
-        caches, logits = self._prefill_chunk(self.params, batch,
-                                             self.state["caches"])
+            spans = [st + n for st, n in zip(starts, lengths)]
+            tables = [self.kv.table(t.rid) for t in tasks]
+            dest_page, dest_off = kvc.chunk_destinations(
+                tables, starts, q_off, lengths, self.page_size, Sq,
+                self.kv.num_pages)
+            # page-aligned kv packing: segment i's prefix occupies its own
+            # whole page slots, so the packed width is pages * page_size,
+            # bucketed (the bucket is a page multiple by construction).
+            pages_needed = sum(kvc.pages_for(sp, self.page_size)
+                               for sp in spans)
+            Sk = self._kv_bucketed(pages_needed * self.page_size)
+            page_list, kseg, kpos = kvc.paged_prefix_lists(
+                tables, spans, self.page_size, Sk // self.page_size)
+            batch = {"tokens": jnp.asarray(toks),
+                     "q_segment_ids": jnp.asarray(qseg),
+                     "q_positions": jnp.asarray(qpos),
+                     "kv_segment_ids": jnp.asarray(kseg[None]),
+                     "kv_positions": jnp.asarray(kpos[None]),
+                     "dest_page": jnp.asarray(dest_page),
+                     "dest_off": jnp.asarray(dest_off),
+                     "page_list": jnp.asarray(page_list[None])}
+        with tr.scope("engine.prefill.launch"):
+            caches, logits = self._prefill_chunk(self.params, batch,
+                                                 self.state["caches"])
         self.state["caches"] = caches
         self._c_prefill_calls.inc()
         self._paged_dirty = True
@@ -1000,8 +1021,15 @@ class ServingEngine:
             self._kv_len_h[t.lane] = t.start + t.length
             self._publish_prefix(r, t.start + t.length)
         self._emit_first_tokens(tasks, logits, q_off)
-        self._account_prefill("prefill_chunk", tasks,
-                              time.perf_counter() - t_w)
+        with tr.scope("engine.account"):
+            cfg = self.model.cfg
+            self._c_gather_elim.inc(int(sum(
+                io_model.gather_hbm_bytes(sp, cfg.head_dim, cfg.num_kv_heads,
+                                          elt=tuning._elt_bytes(cfg.dtype),
+                                          layers=cfg.num_layers)
+                for sp in spans)))
+            self._account_prefill("prefill_chunk", tasks,
+                                  time.perf_counter() - t_w)
 
     # --------------------------------------------- executor: dense prefill
     def _exec_dense(self, tasks: list[ChunkTask]) -> None:
@@ -1080,18 +1108,21 @@ class ServingEngine:
         bq = min(report_block, self.prefill_bucket, s)
         if s % bq:
             return  # bucket not block-aligned; skip the report, not the call
-        ids = jnp.asarray(segs)
-        layout = masks.compile_block_layout(
-            masks.MaskSpec(causal=True, q_segment_ids=ids,
-                           kv_segment_ids=ids), s, s, bq, bq)
-        # one device->host transfer, then numpy: counters must not add
-        # extra sync points to the serving loop.
-        arr = np.asarray(layout.layout)
-        skipped = int((arr == masks.BLOCK_SKIP).sum())
-        total = arr.size
-        self._c_blocks_skipped.inc(skipped)
-        self._c_blocks_total.inc(total)
-        self._g_layout_density.set(1.0 - skipped / total)
+        tr = self.tm.tracer
+        with tr.scope("engine.account"):
+            ids = jnp.asarray(segs)
+            layout = masks.compile_block_layout(
+                masks.MaskSpec(causal=True, q_segment_ids=ids,
+                               kv_segment_ids=ids), s, s, bq, bq)
+            # one device->host transfer, then numpy: counters must not add
+            # extra sync points to the serving loop.
+            with tr.scope("engine.sync"):
+                arr = np.asarray(layout.layout)
+            skipped = int((arr == masks.BLOCK_SKIP).sum())
+            total = arr.size
+            self._c_blocks_skipped.inc(skipped)
+            self._c_blocks_total.inc(total)
+            self._g_layout_density.set(1.0 - skipped / total)
 
     # ------------------------------------------------------ executor: decode
     def _exec_decode(self, decode_lanes: list[int]) -> None:
@@ -1101,6 +1132,7 @@ class ServingEngine:
         # pre-step KV lengths price the split-KV reads (ledger, below)
         kv_lens = [self.scheduler.by_rid[self.slot_req[l].rid].filled
                    for l in lanes]
+        tr = self.tm.tracer
         if self.paged and self._paged_dirty:
             # upload the host allocator's view only when it changed
             # (admission, chunk scatter, page append, finish, preemption).
@@ -1112,47 +1144,49 @@ class ServingEngine:
             # mid-prefill sequence is untouchable by the decode call — its
             # pages are reached only through the chunk path's explicit
             # scatter/gather indices.
-            lane_set = set(lanes)
-            row_rids = [
-                (self.slot_req[l].rid
-                 if l in lane_set and self.slot_req[l] is not None else None)
-                for l in range(self.B)]
-            pt = jnp.asarray(
-                self.kv.table_array(row_rids, self.pages_per_seq))
-            kl = jnp.asarray(self._kv_len_h, jnp.int32)
-            if self.mesh is not None:
-                # commit the host uploads replicated on the mesh so the
-                # whole (donated) state keeps shardings matching in_specs
-                pt = jax.device_put(pt, self._rep)
-                kl = jax.device_put(kl, self._rep)
-            self.state["page_table"] = pt
-            self.state["kv_len"] = kl
-            self._paged_dirty = False
+            with tr.scope("engine.decode.upload"):
+                lane_set = set(lanes)
+                row_rids = [
+                    (self.slot_req[l].rid
+                     if l in lane_set and self.slot_req[l] is not None
+                     else None)
+                    for l in range(self.B)]
+                pt = jnp.asarray(
+                    self.kv.table_array(row_rids, self.pages_per_seq))
+                kl = jnp.asarray(self._kv_len_h, jnp.int32)
+                if self.mesh is not None:
+                    # commit the host uploads replicated on the mesh so the
+                    # whole (donated) state keeps shardings matching
+                    # in_specs
+                    pt = jax.device_put(pt, self._rep)
+                    kl = jax.device_put(kl, self._rep)
+                self.state["page_table"] = pt
+                self.state["kv_len"] = kl
+                self._paged_dirty = False
         t0 = time.perf_counter()
-        tok = jnp.asarray(self.next_token)
         reqs_by_lane = [self.slot_req[l] for l in range(self.B)]
-        self.state, logits = self._decode(self.params, self.state, tok)
+        with tr.scope("engine.decode.launch"):
+            tok = jnp.asarray(self.next_token)
+            self.state, logits = self._decode(self.params, self.state, tok)
         self._c_decode_calls.inc()
         nxt = self._sample_rows(logits[:, 0], reqs_by_lane)
         # _sample_rows materialized host tokens, so the step's device work
         # is done: one wall-clock sample covers every token emitted here.
-        dt = time.perf_counter() - t0
-        for _ in lanes:
-            self._h_tok.observe(dt)
-        hbm = self.tm.ledger.price.decode_bytes(kv_lens)
-        self.tm.ledger.account("decode", hbm_bytes=hbm, wall_s=dt,
-                               tokens=len(lanes))
-        tr = self.tm.tracer
-        if tr.enabled:
-            tr.span("step", "decode", tr.now() - dt, dt,
-                    step=self._step_idx, lanes=list(lanes),
-                    tokens=len(lanes), kv_rows=int(sum(kv_lens)),
-                    hbm_bytes=hbm, census=self._declared_census("decode"),
-                    tiles=self._tile_args())
+        now = time.perf_counter()
+        dt = now - t0
+        with tr.scope("engine.account"):
+            hbm = self.tm.ledger.price.decode_bytes(kv_lens)
+            self.tm.ledger.account("decode", hbm_bytes=hbm, wall_s=dt,
+                                   tokens=len(lanes))
+            if tr.enabled:
+                tr.span("step", "decode", tr.now() - dt, dt,
+                        step=self._step_idx, lanes=list(lanes),
+                        tokens=len(lanes), kv_rows=int(sum(kv_lens)),
+                        hbm_bytes=hbm)
         for lane in lanes:
             req = self.slot_req[lane]
             t = int(nxt[lane])
-            req.output.append(t)
+            self._append_token(req, t, now)
             self.next_token[lane] = t
             self.scheduler.token_appended(req.rid)
             if self.paged:
@@ -1176,16 +1210,15 @@ class ServingEngine:
             tr.span("step", name, tr.now() - dt, dt, step=self._step_idx,
                     lanes=[t.lane for t in tasks],
                     chunks=[[t.start, t.length] for t in tasks],
-                    tokens=tokens, hbm_bytes=hbm,
-                    census=self._declared_census(name),
-                    tiles=self._tile_args())
+                    tokens=tokens, hbm_bytes=hbm)
             for t in tasks:
                 tr.event("req", "chunk", rid=t.rid, lane=t.lane,
                          start=t.start, length=t.length, last=t.last)
 
     def _declared_census(self, kind: str) -> dict[str, int]:
-        """DECLARED per-step collective census for span args — the cheap
-        contract from DESIGN.md §13/§14. The jaxpr-counted census methods
+        """DECLARED per-step collective census for the trace's ``meta``
+        event — the cheap contract from DESIGN.md §13/§14. The
+        jaxpr-counted census methods
         (``decode_collective_census`` / ``prefill_collective_census``)
         PROVE this declaration at construction/test time; re-tracing per
         step would dwarf the step itself."""
@@ -1199,7 +1232,7 @@ class ServingEngine:
         return {"psum": 2 * layers}
 
     def _tile_args(self) -> dict[str, Any]:
-        """Tuner-resolved tile geometry for span args."""
+        """Tuner-resolved tile geometry for the trace's ``meta`` event."""
         p = self.tm.ledger.price
         out: dict[str, Any] = {"block_q": p.block_q, "block_k": p.block_k,
                                "kv_major": p.kv_major}
@@ -1210,30 +1243,38 @@ class ServingEngine:
 
     # ------------------------------------------------------------------ step
     def step(self) -> None:
-        t_step = time.perf_counter()
         self._step_idx += 1
-        plan = self.scheduler.plan_step()
-        # evictions FIRST (they clear lanes the admissions below may
-        # reuse — a prepass eviction frees a lane before admission runs),
-        # and a request both admitted and starve-evicted within this plan
-        # is requeued by _sync_evictions and must never be placed.
-        self._sync_evictions(plan)
-        evicted = ({rid for rid, _ in plan.preempted}
-                   | {rid for rid, _ in plan.finished_capacity})
         tr = self.tm.tracer
-        for rid, lane in plan.admitted:
-            if rid not in evicted:
-                self.slot_req[lane] = self.requests[rid]
-                self._record_prefix_hit(rid)
-                if tr.enabled:
-                    # a re-admission after preemption is the RESUME leg of
-                    # the lifecycle; the validator pairs it with the
-                    # preempt marker.
-                    tr.event("req",
-                             "resume" if rid in self._preempted_rids
-                             else "admit",
-                             rid=rid, lane=lane,
-                             cached=self.scheduler.by_rid[rid].cached)
+        tr.step = self._step_idx
+        with tr.scope("engine.step") as step_scope:
+            self._step(tr)
+            if tr.enabled:
+                step_scope.args.update(self.last_step_stats)
+
+    def _step(self, tr) -> None:
+        with tr.scope("engine.plan"):
+            plan = self.scheduler.plan_step()
+            # evictions FIRST (they clear lanes the admissions below may
+            # reuse — a prepass eviction frees a lane before admission
+            # runs), and a request both admitted and starve-evicted within
+            # this plan is requeued by _sync_evictions and must never be
+            # placed.
+            self._sync_evictions(plan)
+            evicted = ({rid for rid, _ in plan.preempted}
+                       | {rid for rid, _ in plan.finished_capacity})
+            for rid, lane in plan.admitted:
+                if rid not in evicted:
+                    self.slot_req[lane] = self.requests[rid]
+                    self._record_prefix_hit(rid)
+                    if tr.enabled:
+                        # a re-admission after preemption is the RESUME leg
+                        # of the lifecycle; the validator pairs it with the
+                        # preempt marker.
+                        tr.event("req",
+                                 "resume" if rid in self._preempted_rids
+                                 else "admit",
+                                 rid=rid, lane=lane,
+                                 cached=self.scheduler.by_rid[rid].cached)
 
         zero = [t for t in plan.prefill if t.start == 0]
         suffix = [t for t in plan.prefill if t.start > 0]
@@ -1255,26 +1296,22 @@ class ServingEngine:
         elif zero:
             self._exec_dense(zero)
 
-        active = sum(r is not None for r in self.slot_req)
-        self._g_peak_active.max_update(active)
         g = self._g_step
-        g["active"].set(active)
-        g["occupancy"].set(active / self.B)
-        if self.paged:
-            g["pool_utilization"].set(self.kv.utilization())
-        g["prefill_tokens"].set(sum(t.length for t in plan.prefill))
-        g["decode_tokens"].set(len(plan.decode_lanes))
-        g["deferred_chunks"].set(plan.deferred_chunks)
-        g["queued"].set(len(self.scheduler.queue))
-        self._stepped = True
+        with tr.scope("engine.account"):
+            active = sum(r is not None for r in self.slot_req)
+            self._g_peak_active.max_update(active)
+            g["active"].set(active)
+            g["occupancy"].set(active / self.B)
+            if self.paged:
+                g["pool_utilization"].set(self.kv.utilization())
+            g["prefill_tokens"].set(sum(t.length for t in plan.prefill))
+            g["decode_tokens"].set(len(plan.decode_lanes))
+            g["deferred_chunks"].set(plan.deferred_chunks)
+            g["queued"].set(len(self.scheduler.queue))
+            self._stepped = True
         self._exec_decode(plan.decode_lanes)
         # post-decode queue depth (finish/reclaim just happened)
         g["queued"].set(len(self.scheduler.queue))
-        if tr.enabled:
-            dt = time.perf_counter() - t_step
-            stats = self.last_step_stats
-            tr.span("stepsum", "step", tr.now() - dt, dt,
-                    step=self._step_idx, **stats)
 
     def run(self, max_steps: int = 10_000, on_step=None) -> list[Request]:
         """Drive the engine to drain. ``on_step(engine)`` is called after

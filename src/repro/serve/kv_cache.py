@@ -53,6 +53,7 @@ import jax
 import numpy as np
 
 from repro.core import masks
+from repro.telemetry import scopes
 from repro.telemetry.metrics import MetricsRegistry
 
 __all__ = ["PagedKVCache", "scatter_packed_segments",
@@ -418,4 +419,5 @@ def scatter_packed_segments(pool_caches, packed_caches, dest_page, dest_off):
         src = packed[:, 0].astype(pool.dtype)            # (L, hkv, S, hd)
         return pool.at[:, :, dest_page, dest_off, :].set(src, mode="drop")
 
-    return jax.tree.map(scat, pool_caches, packed_caches)
+    with scopes.named(scopes.KV_WRITE):
+        return jax.tree.map(scat, pool_caches, packed_caches)

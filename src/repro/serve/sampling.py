@@ -24,6 +24,8 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from repro.telemetry.scopes import SAMPLE, scoped
+
 __all__ = ["SamplingParams", "sample_tokens"]
 
 
@@ -62,6 +64,7 @@ def _sample_one(logits, seed, count, temperature, top_p):
     return jnp.where(greedy, jnp.argmax(lg), sampled).astype(jnp.int32)
 
 
+@scoped(SAMPLE)
 def sample_tokens(logits, seeds, counts, temperature, top_p):
     """(n, V) logits + per-row (seed, count, temperature, top_p) -> (n,)
     int32 tokens. vmapped over rows, so each row's draw is independent of

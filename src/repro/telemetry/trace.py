@@ -1,30 +1,44 @@
-"""Structured serving trace: per-step spans + per-request lifecycle events.
+"""Structured serving trace: host spans, per-call step spans and
+per-request lifecycle events.
 
-The ``Tracer`` is an append-only in-memory recorder.  Producers emit two
+The ``Tracer`` is an append-only in-memory recorder.  Producers emit three
 shapes (DESIGN.md §15 documents the schema):
 
-- **spans** (``span()``): a timed interval — one per executed step kind
-  (``prefill_zero`` / ``prefill_chunk`` / ``prefill_dense`` / ``decode``)
-  plus one ``step`` summary umbrella.  Span args carry the lanes, chunk
-  sizes, declared collective census, tuner-resolved tiles, and the IO
-  ledger's predicted HBM bytes for that interval.
+- **scopes** (``scope()``): a context manager around a stretch of host
+  work.  Entered, it also opens ``jax.profiler.TraceAnnotation(name)``, so
+  the same interval lands in a profiler trace's host plane on the device
+  trace's clock, beside the device ops it launched or waited for.  The
+  event (kind ``scope``) is appended on exit with its ``id`` (the order in
+  which scopes were opened), ``parent`` (the enclosing open scope's id, or
+  -1) and the engine's ``step`` index.  The serving engine names its
+  phases ``engine.step`` > ``engine.plan`` / ``engine.prefill.build`` /
+  ``engine.prefill.launch`` / ``engine.decode.upload`` /
+  ``engine.decode.launch`` / ``engine.sync`` / ``engine.account``; every
+  blocking device-to-host fetch is an ``engine.sync``.
+- **spans** (``span()``): a timed interval, one per model call
+  (``prefill_zero`` / ``prefill_chunk`` / ``prefill_dense`` / ``decode``),
+  carrying the lanes, chunk sizes, tokens and the IO ledger's predicted
+  HBM bytes.  These are host intervals: a call's device time is in the
+  profiler's trace, not here.
 - **markers** (``event()``): an instant — request lifecycle points
   (``submit``/``admit``/``resume``/``chunk``/``first_token``/``preempt``/
-  ``prefix_hit``/``finish``) and scheduler decisions with reasons
-  (``defer``/``evict``).
+  ``prefix_hit``/``finish``), scheduler decisions with reasons
+  (``defer``/``evict``), and one ``meta`` event per engine with what is
+  constant for its lifetime (declared collective census, tiles).
 
 Overhead contract: when ``enabled`` is False every emit is a single
-attribute check.  Hot-path call sites guard ``if tracer.enabled:``
-*before* building kwargs, so the disabled mode allocates nothing —
-``tests/test_telemetry.py`` pins this with tracemalloc.
+attribute check, and ``scope()`` returns one shared no-op context.
+Hot-path call sites guard ``if tracer.enabled:`` *before* building kwargs,
+so the disabled mode allocates nothing — ``tests/test_telemetry.py`` pins
+this with tracemalloc.
 
 Exports: ``to_jsonl`` dumps the raw events one-per-line;
 ``to_chrome_trace`` converts to Chrome trace-event JSON (load at
 ``chrome://tracing`` or https://ui.perfetto.dev).  Step spans land on an
-``engine`` process with one thread lane per step kind; request lifecycle
-phases are *reconstructed* from the markers into contiguous spans
-(queued → prefill → decode, with ``preempted`` gaps) on a ``requests``
-process, one thread per request id.
+``engine`` process with one thread lane per step kind and scopes on its
+``host`` lane; request lifecycle phases are *reconstructed* from the
+markers into contiguous spans (queued → prefill → decode, with
+``preempted`` gaps) on a ``requests`` process, one thread per request id.
 """
 
 from __future__ import annotations
@@ -36,21 +50,76 @@ import time
 PID_ENGINE = 1
 PID_REQUESTS = 2
 _STEP_TIDS = {"step": 0, "prefill_zero": 1, "prefill_chunk": 2,
-              "prefill_dense": 3, "decode": 4, "sched": 5}
+              "prefill_dense": 3, "decode": 4, "sched": 5, "host": 6}
 
 # Request phases, in lifecycle order (used by the validator too).
 REQ_PHASES = ("queued", "prefill", "decode", "preempted")
 
 
-class Tracer:
-    """Near-zero-overhead event recorder; no-op when disabled."""
+class _NoScope:
+    """What ``Tracer.scope`` returns when disabled: one shared instance."""
 
-    __slots__ = ("enabled", "events", "_t0")
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+_NO_SCOPE = _NoScope()
+
+
+class _Scope:
+    """One open host scope of an enabled tracer; ``args`` may be extended
+    before exit."""
+
+    __slots__ = ("tracer", "name", "args", "id", "parent", "t0", "_ann")
+
+    def __init__(self, tracer: "Tracer", name: str, args: dict):
+        self.tracer, self.name, self.args = tracer, name, args
+
+    def __enter__(self):
+        # imported here, so the telemetry bundle imports without jax
+        from jax.profiler import TraceAnnotation
+
+        tr = self.tracer
+        self.parent = tr._open[-1] if tr._open else -1
+        self.id = tr._next_id
+        tr._next_id += 1
+        tr._open.append(self.id)
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
+        self.t0 = tr.now()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        tr = self.tracer
+        t1 = tr.now()
+        self._ann.__exit__(*exc)
+        tr._open.pop()
+        ev = {"ts": self.t0, "dur": t1 - self.t0, "kind": "scope",
+              "name": self.name, "id": self.id, "parent": self.parent,
+              "step": tr.step}
+        ev.update(self.args)
+        tr.events.append(ev)
+        return False
+
+
+class Tracer:
+    """Near-zero-overhead event recorder; no-op when disabled. ``step`` is
+    the engine's current step index, stamped on every scope."""
+
+    __slots__ = ("enabled", "events", "step", "_t0", "_open", "_next_id")
 
     def __init__(self, enabled: bool = False):
         self.enabled = bool(enabled)
         self.events: list[dict] = []
+        self.step = 0
         self._t0 = time.perf_counter()
+        self._open: list[int] = []
+        self._next_id = 0
 
     def now(self) -> float:
         """Seconds since tracer creation (trace-relative clock)."""
@@ -73,6 +142,14 @@ class Tracer:
               "name": name}
         ev.update(fields)
         self.events.append(ev)
+
+    def scope(self, name: str, **args):
+        """Context manager recording the host interval it encloses as a
+        ``scope`` event and as a profiler ``TraceAnnotation``; the shared
+        no-op context when disabled."""
+        if not self.enabled:
+            return _NO_SCOPE
+        return _Scope(self, name, args)
 
     # -- exports ------------------------------------------------------------
 
@@ -167,11 +244,17 @@ def chrome_trace_doc(events: list[dict]) -> dict:
     rids = set()
     for ev in events:
         kind = ev.get("kind")
-        if kind in ("step", "stepsum"):
-            tid = _STEP_TIDS.get(ev["name"], _STEP_TIDS["step"])
+        if kind in ("step", "scope"):
+            tid = (_STEP_TIDS["host"] if kind == "scope"
+                   else _STEP_TIDS.get(ev["name"], _STEP_TIDS["step"]))
             te.append({"name": ev["name"], "cat": kind, "ph": "X",
                        "ts": _us(ev["ts"]), "dur": _us(ev.get("dur", 0.0)),
                        "pid": PID_ENGINE, "tid": tid, "args": _args_of(ev)})
+        elif kind == "meta":
+            te.append({"name": ev["name"], "cat": "meta", "ph": "i",
+                       "ts": _us(ev["ts"]), "pid": PID_ENGINE,
+                       "tid": _STEP_TIDS["host"], "s": "p",
+                       "args": _args_of(ev)})
         elif kind == "sched":
             te.append({"name": ev["name"], "cat": "sched", "ph": "i",
                        "ts": _us(ev["ts"]), "pid": PID_ENGINE,

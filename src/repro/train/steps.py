@@ -28,6 +28,7 @@ from repro.distributed.zero import zero1_state_specs
 from repro.models.model_zoo import Model
 from repro.optim.optimizers import (Optimizer, apply_updates,
                                     clip_by_global_norm)
+from repro.telemetry import scopes
 
 
 def make_train_step(model: Model, optimizer: Optimizer, *,
@@ -65,9 +66,10 @@ def make_train_step(model: Model, optimizer: Optimizer, *,
             m0 = jax.tree.map(jnp.float32, m0)
             (grads, metrics), _ = jax.lax.scan(body, (g0, m0), micro)
 
-        grads, gnorm = clip_by_global_norm(grads, clip_norm)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = apply_updates(params, updates)
+        with scopes.named(scopes.OPTIMIZER):
+            grads, gnorm = clip_by_global_norm(grads, clip_norm)
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = apply_updates(params, updates)
         metrics = dict(metrics)
         metrics["grad_norm"] = gnorm
         return params, opt_state, metrics

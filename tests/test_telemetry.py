@@ -115,17 +115,47 @@ def test_percentile_edge_cases():
 def test_tracer_disabled_records_nothing_and_allocates_nothing():
     tr = Tracer(enabled=False)
     # the call-site contract guards with `if tr.enabled:` — but even the
-    # unguarded call must early-return without touching the buffer.
+    # unguarded call must early-return without touching the buffer; a
+    # disabled scope is one shared no-op context.
+    assert tr.scope("engine.step") is tr.scope("engine.sync", step=1)
     tracemalloc.start()
     before, _ = tracemalloc.get_traced_memory()
     for i in range(10_000):
         if tr.enabled:
             tr.event("req", "submit", rid=i)
             tr.span("step", "decode", 0.0, 1.0, step=i)
+        with tr.scope("engine.step"):
+            with tr.scope("engine.sync"):
+                pass
     after, _ = tracemalloc.get_traced_memory()
     tracemalloc.stop()
     assert tr.events == []
     assert after - before < 16_384          # no per-emit retention
+
+
+def test_tracer_scopes_nest_and_append_on_exit():
+    tr = Tracer(enabled=True)
+    tr.step = 7
+    with tr.scope("engine.step") as outer:
+        with tr.scope("engine.plan"):
+            pass
+        with tr.scope("engine.sync", rows=3):
+            pass
+        outer.args["active"] = 2
+    inner, sync, step = tr.events            # appended as each one exits
+    assert [e["name"] for e in tr.events] == [
+        "engine.plan", "engine.sync", "engine.step"]
+    assert all(e["kind"] == "scope" and e["step"] == 7 for e in tr.events)
+    assert step["parent"] == -1 and step["active"] == 2
+    assert inner["parent"] == sync["parent"] == step["id"]
+    assert sync["rows"] == 3
+    for child in (inner, sync):
+        assert step["ts"] <= child["ts"]
+        assert child["ts"] + child["dur"] <= step["ts"] + step["dur"]
+    doc = chrome_trace_doc(tr.events)
+    host = [e for e in doc["traceEvents"] if e.get("cat") == "scope"]
+    assert len(host) == 3 and {e["tid"] for e in host} == {6}
+    assert all(e["ph"] == "X" and e["pid"] == 1 for e in host)
 
 
 def test_tracer_event_and_span_shapes():
@@ -240,6 +270,71 @@ def test_preemption_resume_lifecycle_in_trace(setup):
     assert sum(snap["sched_preemptions"]["series"].values()) >= 1
     assert eng.tm.ledger.total_bytes() > 0
     assert eng.tm.ledger.by_kind["decode"]["tokens"] > 0
+
+
+def test_engine_scopes_nest_and_sync_inside_step(setup):
+    """A traced engine's host spans: every scope lies inside its parent,
+    every engine.sync inside an engine.step, which carries the step's
+    stats; constant arguments are one meta event, not per span."""
+    cfg, model, params = setup
+    eng = ServingEngine(model, params, num_slots=2, capacity=64,
+                        paged=True, page_size=8, chunk_size=8,
+                        token_budget=12, trace=True)
+    eng.submit(list(range(1, 20)), max_new_tokens=4)
+    eng.submit(list(range(30, 41)), max_new_tokens=3)
+    eng.run()
+    evs = eng.tm.tracer.events
+    scopes = {e["id"]: e for e in evs if e["kind"] == "scope"}
+    names = {e["name"] for e in scopes.values()}
+    assert {"engine.step", "engine.plan", "engine.prefill.build",
+            "engine.prefill.launch", "engine.decode.upload",
+            "engine.decode.launch", "engine.sync",
+            "engine.account"} <= names
+    eps = 1e-9
+    for e in scopes.values():
+        if e["parent"] < 0:
+            continue
+        p = scopes[e["parent"]]
+        assert p["ts"] - eps <= e["ts"]
+        assert e["ts"] + e["dur"] <= p["ts"] + p["dur"] + eps
+        assert e["step"] == p["step"]
+
+    def root(e):
+        while e["parent"] >= 0:
+            e = scopes[e["parent"]]
+        return e
+
+    syncs = [e for e in scopes.values() if e["name"] == "engine.sync"]
+    assert syncs and all(root(e)["name"] == "engine.step" for e in syncs)
+    steps = [e for e in scopes.values() if e["name"] == "engine.step"]
+    assert [e["step"] for e in steps] == list(range(1, len(steps) + 1))
+    assert all({"active", "prefill_tokens", "decode_tokens", "queued"}
+               <= set(e) for e in steps)
+    assert all("census" not in e and "tiles" not in e
+               for e in evs if e["kind"] == "step")
+    meta = [e for e in evs if e["kind"] == "meta"]
+    assert len(meta) == 1 and set(meta[0]) >= {"census", "tiles"}
+    assert not any(e["kind"] == "stepsum" for e in evs)
+    assert validate_chrome_trace(chrome_trace_doc(evs)) == []
+
+
+def test_tok_latency_is_the_gap_a_stream_sees(setup):
+    """serve_tok_latency_s observes one gap per token after a request's
+    first, from one token's arrival to the next, prefill steps between
+    them included."""
+    cfg, model, params = setup
+    eng = ServingEngine(model, params, num_slots=2, capacity=64,
+                        paged=True, page_size=8, chunk_size=8,
+                        token_budget=10)
+    eng.submit(list(range(1, 6)), max_new_tokens=6)
+    eng.submit(list(range(30, 60)), max_new_tokens=2)   # 4 chunks, later
+    done = eng.run()
+    gaps = eng.tok_latencies
+    assert len(gaps) == sum(len(r.output) - 1 for r in done)
+    first = done[0] if done[0].rid == 0 else done[1]
+    assert sum(gaps) == pytest.approx(
+        sum(r.t_last - r.t_first for r in done))
+    assert first.t_last > first.t_first
 
 
 def test_prefix_hit_annotated_and_credited(setup):
