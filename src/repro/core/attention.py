@@ -157,43 +157,71 @@ def decode_attention(
                             num_splits=spec.num_decode_splits,
                             window=spec.window, kv_mask=kv_mask,
                             shards=spec.tp_shards)
-    # XLA path: GQA-NATIVE masked softmax over the cache. q is reshaped to
-    # (b, hkv, rep, 1, d) and contracted against the UNEXPANDED cache —
-    # repeat_kv would broadcast-materialize the cache and force GSPMD to
-    # all-gather the sequence-sharded capacity dim (measured: 2.1 GB/layer
-    # on qwen3 decode_32k — §Roofline decode collective term). Keeping the
-    # cache un-reshaped leaves the capacity dim sharded through the scores;
-    # the softmax reduction and P@V contraction then reduce over it with
-    # small collectives (the XLA analogue of split-KV flash decode).
+    kvm = masks.decode_kv_valid(kv_len, k_cache.shape[2], window=spec.window,
+                                kv_mask=kv_mask)
+    return _masked_decode(q, k_cache, v_cache, kvm, scale, "bksd")
+
+
+def _masked_decode(q, k, v, kv_valid, scale, kv_axes):
+    """The XLA decode path: GQA-NATIVE masked softmax over a cache whose
+    axes ``kv_axes`` names, "bksd" (the dense slot cache) or "bskd" (rows
+    gathered from the lane-dense page pool). q is reshaped to
+    (b, hkv, rep, 1, d) and contracted against the UNEXPANDED cache —
+    repeat_kv would broadcast-materialize the cache and force GSPMD to
+    all-gather the sequence-sharded capacity dim (measured: 2.1 GB/layer
+    on qwen3 decode_32k — §Roofline decode collective term). Keeping the
+    cache un-reshaped leaves the capacity dim sharded through the scores;
+    the softmax reduction and P@V contraction then reduce over it with
+    small collectives (the XLA analogue of split-KV flash decode)."""
     b, hq, sq, d = q.shape
-    _, hkv, capacity, _ = k_cache.shape
-    rep = hq // hkv
+    hkv = k.shape[kv_axes.index("k")]
     if scale is None:
         scale = 1.0 / (d ** 0.5)
-    qg = q.reshape(b, hkv, rep, sq, d)
-    s = jnp.einsum("bkrqd,bksd->bkrqs", qg.astype(jnp.float32),
-                   k_cache.astype(jnp.float32)) * scale
+    qg = q.reshape(b, hkv, hq // hkv, sq, d)
+    s = jnp.einsum(f"bkrqd,{kv_axes}->bkrqs", qg.astype(jnp.float32),
+                   k.astype(jnp.float32)) * scale
     # the same validity band the decode kernel compiles its layout from
     # (kv_len + window + optional slot mask), masked with the one NEG_INF
     # sentinel every impl shares.
-    kvm = masks.decode_kv_valid(kv_len, capacity, window=spec.window,
-                                kv_mask=kv_mask)
-    s = jnp.where(kvm[:, None, None, None, :], s, masks.NEG_INF)
+    s = jnp.where(kv_valid[:, None, None, None, :], s, masks.NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.where(s <= masks.NEG_INF / 2, 0.0, jnp.exp(s - m))
     l = jnp.sum(p, axis=-1, keepdims=True)
     # fully-masked rows (kv_len == 0 / garbage batch rows) emit zeros, the
     # same convention as the split-KV kernel's empty-partial merge.
     p = p / jnp.where(l == 0.0, 1.0, l)
-    o = jnp.einsum("bkrqs,bksd->bkrqd", p, v_cache.astype(jnp.float32))
+    o = jnp.einsum(f"bkrqs,{kv_axes}->bkrqd", p, v.astype(jnp.float32))
     return o.reshape(b, hq, sq, d).astype(q.dtype)
+
+
+def _gather_pages(pool, layer, pages, d):
+    """Layer ``layer``'s rows of the lane-dense pool (L, num_pages,
+    page_size, hkv*d) at ``pages`` (b, T), as (b, T*page_size, hkv, d):
+    ONE gather indexed by (layer, page), so no layer of the pool is sliced
+    out first. Dead entries (negative) read page 0; the caller masks
+    them."""
+    num_pages, page_size, width = pool.shape[1:]
+    b, T = pages.shape
+    rows = pool[layer, jnp.clip(pages, 0, num_pages - 1)]
+    return rows.reshape(b, T * page_size, width // d, d)
+
+
+def _head_major(pool, layer, d):
+    """Layer ``layer`` of the lane-dense pool re-viewed as the
+    (hkv, num_pages, page_size, d) operand the paged Pallas kernels read.
+    A copy of that layer on every call, on the kernel path only, until the
+    kernels read lane-dense pages (ROADMAP Queue 1 item 3)."""
+    num_pages, page_size, width = pool.shape[1:]
+    return pool[layer].reshape(
+        num_pages, page_size, width // d, d).transpose(2, 0, 1, 3)
 
 
 @scoped(ATTENTION)
 def paged_prefill_attention(
     q: jax.Array,            # (b, hq, sq, d) — the suffix chunk's queries
-    k_pool: jax.Array,       # (hkv, num_pages, page_size, d) — shared pool
+    k_pool: jax.Array,       # (L, num_pages, page_size, hkv*d) — shared pool
     v_pool: jax.Array,
+    layer,                   # the calling layer's index into the pool
     page_list: jax.Array,    # (b, T) int32; negative = dead (never read)
     spec: AttentionSpec,
     *,
@@ -208,44 +236,39 @@ def paged_prefill_attention(
     The Pallas path (``impl`` in {pallas, block_sparse}) hands the page
     list to ``flash_prefill_paged``: the kv BlockSpec index_map resolves
     physical pages from the scalar-prefetched table, so the kernel attends
-    the pool directly — one page DMA per kv block, SKIP pages never read,
-    and zero per-layer ``gather_sources`` copies on the serving hot path.
+    the (re-viewed) layer directly — one page DMA per kv block, SKIP pages
+    never read.
 
-    Every other impl is the XLA parity oracle: gather the pages into the
-    logical (b, hkv, T*page_size, d) view (clamped to page 0 on dead
-    entries) and reuse ``attention`` verbatim. Dead rows carry the
+    Every other impl is the XLA parity oracle: gather the layer's pages
+    into the logical (b, hkv, T*page_size, d) view (clamped to page 0 on
+    dead entries) and reuse ``attention`` verbatim. Dead rows carry the
     SEG_PAD_KV / POS_PAD sentinels in ``kv_segment_ids``/``kv_positions``,
     so the shared fused mask kills them on both paths — validity is one
     definition, not two.
     """
+    d = q.shape[-1]
+    common = dict(q_positions=q_positions, kv_positions=kv_positions,
+                  q_segment_ids=q_segment_ids, kv_segment_ids=kv_segment_ids,
+                  scale=scale)
     if spec.impl in ("pallas", "block_sparse"):
         return kops.flash_prefill_paged(
-            q, k_pool, v_pool, page_list,
-            q_positions=q_positions, kv_positions=kv_positions,
-            q_segment_ids=q_segment_ids, kv_segment_ids=kv_segment_ids,
-            causal=spec.causal, window=spec.window, scale=scale,
+            q, _head_major(k_pool, layer, d), _head_major(v_pool, layer, d),
+            page_list, causal=spec.causal, window=spec.window,
             block_q=spec.block_q, variant=spec.variant,
-            shards=spec.tp_shards)
-    hkv, num_pages, page_size, d = k_pool.shape
-    b, T = page_list.shape
-    safe = jnp.clip(page_list, 0, num_pages - 1)
+            shards=spec.tp_shards, **common)
 
     def gather(pool):
-        pages = pool[:, safe]                    # (hkv, b, T, page_size, d)
-        return pages.transpose(1, 0, 2, 3, 4).reshape(
-            b, hkv, T * page_size, d)
+        return _gather_pages(pool, layer, page_list, d).transpose(0, 2, 1, 3)
 
-    return attention(
-        q, gather(k_pool), gather(v_pool), spec,
-        q_segment_ids=q_segment_ids, kv_segment_ids=kv_segment_ids,
-        q_positions=q_positions, kv_positions=kv_positions, scale=scale)
+    return attention(q, gather(k_pool), gather(v_pool), spec, **common)
 
 
 @scoped(ATTENTION)
 def paged_decode_attention(
     q: jax.Array,            # (b, hq, 1, d)
-    k_pool: jax.Array,       # (hkv, num_pages, page_size, d) — shared pool
+    k_pool: jax.Array,       # (L, num_pages, page_size, hkv*d) — shared pool
     v_pool: jax.Array,
+    layer,                   # the calling layer's index into the pool
     page_table: jax.Array,   # (b, pages_per_seq) int32; negative = unallocated
     kv_len: jax.Array,       # (b,) int32
     spec: AttentionSpec,
@@ -256,27 +279,25 @@ def paged_decode_attention(
 
     With ``spec.use_decode_kernel`` the split-KV Pallas kernel walks the
     page table directly (one page DMA per kv block, SKIP pages never
-    fetched). The XLA parity path gathers the sequence's pages into the
-    logical (b, hkv, T*page_size, d) view and reuses ``decode_attention``
-    verbatim — unallocated table entries become masked slots (gather is
-    clamped to page 0, then killed by the kv_mask), so both paths derive
-    validity from the same ``masks.decode_kv_valid`` band.
+    fetched) over the re-viewed layer. The XLA parity path gathers the
+    sequence's pages as (b, T*page_size, hkv, d) rows and contracts them
+    GQA-natively (``_masked_decode``), with no transpose of the pool —
+    unallocated table entries become masked slots (gather is clamped to
+    page 0, then killed by the kv_mask), so both paths derive validity
+    from the same ``masks.decode_kv_valid`` band.
     """
+    d = q.shape[-1]
     if spec.use_decode_kernel:
-        return flash_decode_paged(q, k_pool, v_pool, page_table, kv_len,
-                                  scale=scale,
+        return flash_decode_paged(q, _head_major(k_pool, layer, d),
+                                  _head_major(v_pool, layer, d),
+                                  page_table, kv_len, scale=scale,
                                   num_splits=spec.num_decode_splits,
                                   window=spec.window,
                                   shards=spec.tp_shards)
-    hkv, num_pages, page_size, d = k_pool.shape
-    b, T = page_table.shape
-    safe = jnp.clip(page_table, 0, num_pages - 1)
-    def gather(pool):
-        pages = pool[:, safe]                    # (hkv, b, T, page_size, d)
-        return pages.transpose(1, 0, 2, 3, 4).reshape(
-            b, hkv, T * page_size, d)
+    page_size = k_pool.shape[2]
     alloc = jnp.repeat(page_table >= 0, page_size, axis=1)   # (b, T*ps)
-    return decode_attention(
-        q, gather(k_pool), gather(v_pool), kv_len,
-        dataclasses.replace(spec, use_decode_kernel=False),
-        kv_mask=alloc, scale=scale)
+    kvm = masks.decode_kv_valid(kv_len, alloc.shape[1], window=spec.window,
+                                kv_mask=alloc)
+    return _masked_decode(q, _gather_pages(k_pool, layer, page_table, d),
+                          _gather_pages(v_pool, layer, page_table, d),
+                          kvm, scale, "bskd")

@@ -237,14 +237,16 @@ def decode_attention_step(params, cfg: ModelConfig, x, cache, kv_len,
 
 def init_paged_kv_cache(cfg: ModelConfig, num_pages: int, page_size: int,
                         dtype):
-    """One layer's slice of the shared page pool. Unlike the dense per-slot
-    cache there is no batch dim: pages are the unit of allocation and any
+    """The shared page pool of every layer, LANE-DENSE: each K/V leaf is
+    (L, num_pages, page_size, hkv*hd), so a page is one contiguous
+    (page_size, hkv*hd) tile and the layer loop writes and reads it with
+    one scatter and one gather indexed by (layer, page), with no layout
+    change of the pool (DESIGN.md §6). Unlike the dense per-slot cache
+    there is no batch dim: pages are the unit of allocation and any
     sequence's page table may point anywhere in the pool."""
-    hkv, hd = cfg.num_kv_heads, cfg.head_dim
-    return {
-        "k": jnp.zeros((hkv, num_pages, page_size, hd), dtype),
-        "v": jnp.zeros((hkv, num_pages, page_size, hd), dtype),
-    }
+    shape = (cfg.num_layers, num_pages, page_size,
+             cfg.num_kv_heads * cfg.head_dim)
+    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
 def paged_kv_cache_specs():
@@ -299,7 +301,7 @@ def _sp_gather_kv(k: jax.Array, v: jax.Array, cfg: ModelConfig):
     return full[0], full[1]
 
 
-def chunk_prefill_attention_step(params, cfg: ModelConfig, x, pool,
+def chunk_prefill_attention_step(params, cfg: ModelConfig, x, pool, layer,
                                  dest_page, dest_off, page_list,
                                  q_seg, kv_seg, q_pos, kv_pos,
                                  *, spec: AttentionSpec | None = None):
@@ -307,8 +309,10 @@ def chunk_prefill_attention_step(params, cfg: ModelConfig, x, pool,
     IN PLACE (DESIGN.md §10, §11).
 
     x: (1, S, d_model) — the NEXT prefill chunks of several sequences
-    packed into one varlen call (q_seg isolates them). The new K/V rows
-    are scattered straight into pool pages at ``(dest_page, dest_off)``
+    packed into one varlen call (q_seg isolates them); ``pool`` is every
+    layer's pool and ``layer`` this layer's index into it. The new K/V rows
+    are scattered straight into pool pages at ``(layer, dest_page,
+    dest_off)``
     (logical positions ``hist_i + r``, pages grown chunk-by-chunk); the kv
     side — each segment's FULL logical prefix ``[0, hist_i + C_i)``,
     history written by earlier chunks plus the rows just scattered — is
@@ -335,27 +339,30 @@ def chunk_prefill_attention_step(params, cfg: ModelConfig, x, pool,
     q, k, v = _project_qkv(params, cfg, x, x, q_pos, q_pos)
     k, v = _sp_gather_kv(k, v, cfg)
 
-    def _scat(c, new):  # c: (hkv, P, ps, hd); new: (1, hkv, S, hd)
-        return c.at[:, dest_page, dest_off, :].set(new[0].astype(c.dtype),
-                                                   mode="drop")
+    def _scat(c, new):  # c: (L, P, ps, hkv*hd); new: (1, hkv, S, hd)
+        rows = new[0].transpose(1, 0, 2).reshape(new.shape[2], -1)
+        return c.at[layer, dest_page, dest_off].set(rows.astype(c.dtype),
+                                                    mode="drop")
 
     with scopes.named(scopes.KV_WRITE):
         pool = {"k": _scat(pool["k"], k), "v": _scat(pool["v"], v)}
     spec = spec or attn_spec_from_config(cfg)
-    o = paged_prefill_attention(q, pool["k"], pool["v"], page_list, spec,
+    o = paged_prefill_attention(q, pool["k"], pool["v"], layer, page_list,
+                                spec,
                                 q_segment_ids=q_seg, kv_segment_ids=kv_seg,
                                 q_positions=q_pos, kv_positions=kv_pos)
     return _tp_reduce(_merge_heads(o) @ params["wo"], cfg), pool
 
 
-def paged_decode_attention_step(params, cfg: ModelConfig, x, pool,
+def paged_decode_attention_step(params, cfg: ModelConfig, x, pool, layer,
                                 page_table, kv_len,
                                 *, spec: AttentionSpec | None = None):
     """Single-token decode against the shared page pool.
 
-    x: (b, 1, d_model); pool leaves (hkv, num_pages, page_size, hd);
-    page_table: (b, pages_per_seq) int32, negative = unallocated;
-    kv_len: (b,) logical lengths. Writes the new K/V into physical page
+    x: (b, 1, d_model); pool leaves (L, num_pages, page_size, hkv*hd),
+    ``layer`` this layer's index into them; page_table: (b, pages_per_seq)
+    int32, negative = unallocated; kv_len: (b,) logical lengths. Writes
+    the new K/V into layer ``layer``, physical page
     ``page_table[b, kv_len // page_size]`` at offset ``kv_len % page_size``
     (one batched scatter; rows whose table entry is unallocated — idle
     batch rows — are DROPPED, so they can never corrupt another sequence's
@@ -366,7 +373,7 @@ def paged_decode_attention_step(params, cfg: ModelConfig, x, pool,
     positions = kv_len[:, None]                  # (b, 1) position of new token
     q, k, v = _project_qkv(params, cfg, x, x, positions, positions)
 
-    num_pages, page_size = pool["k"].shape[1], pool["k"].shape[2]
+    num_pages, page_size = pool["k"].shape[1:3]
     T = page_table.shape[1]
     lp = jnp.minimum(kv_len // page_size, T - 1)
     off = kv_len % page_size
@@ -378,13 +385,13 @@ def paged_decode_attention_step(params, cfg: ModelConfig, x, pool,
     # dropped write.
     phys = jnp.where((phys < 0) | (kv_len >= T * page_size), num_pages, phys)
 
-    def _upd(c, new):  # c: (hkv, P, ps, hd); new: (b, hkv, 1, hd)
-        rows = new[:, :, 0].transpose(1, 0, 2).astype(c.dtype)  # (hkv, b, hd)
-        return c.at[:, phys, off, :].set(rows, mode="drop")
+    def _upd(c, new):  # c: (L, P, ps, hkv*hd); new: (b, hkv, 1, hd)
+        rows = new.reshape(new.shape[0], -1).astype(c.dtype)  # (b, hkv*hd)
+        return c.at[layer, phys, off].set(rows, mode="drop")
 
     with scopes.named(scopes.KV_WRITE):
         pool = {"k": _upd(pool["k"], k), "v": _upd(pool["v"], v)}
     spec = spec or attn_spec_from_config(cfg)
-    o = paged_decode_attention(q, pool["k"], pool["v"], page_table,
+    o = paged_decode_attention(q, pool["k"], pool["v"], layer, page_table,
                                kv_len + 1, spec)
     return _tp_reduce(_merge_heads(o) @ params["wo"], cfg), pool

@@ -187,8 +187,8 @@ class Model:
 
     def init_paged_decode_state(self, batch: int, num_pages: int,
                                 page_size: int, pages_per_seq: int):
-        """Decode state over a shared page pool: per-layer pools
-        (L, hkv, num_pages, page_size, hd), a (batch, pages_per_seq) page
+        """Decode state over a shared page pool: lane-dense pools
+        (L, num_pages, page_size, hkv*hd), a (batch, pages_per_seq) page
         table (negative = unallocated), and logical lengths. The batch dim
         costs no cache memory — rows are just decode lanes; all KV bytes
         live in the pool."""

@@ -286,60 +286,80 @@ def apply_block_decode(params: Params, cfg: ModelConfig, x, cache, kv_len,
 
 def init_paged_decode_cache(cfg: ModelConfig, num_pages: int, page_size: int,
                             dtype):
-    """Per-layer page pools stacked on a leading layer axis:
-    (L, hkv, num_pages, page_size, hd) per K/V leaf. Paged decode is an
-    attention-family feature (a page holds token-indexed K/V rows);
-    SSM/hybrid recurrent state and encoder streams have no such rows —
-    ``Model.supports_paged_decode`` gates those families to the dense path.
+    """Every layer's page pool, lane-dense: (L, num_pages, page_size,
+    hkv*hd) per K/V leaf. Paged decode is an attention-family feature (a
+    page holds token-indexed K/V rows); SSM/hybrid recurrent state and
+    encoder streams have no such rows — ``Model.supports_paged_decode``
+    gates those families to the dense path.
     """
     assert cfg.family in ("dense", "moe") and not cfg.hybrid, cfg.family
-
-    def one_layer(_):
-        return {"kv": attn_mod.init_paged_kv_cache(cfg, num_pages,
-                                                   page_size, dtype)}
-
-    return jax.vmap(one_layer)(jnp.arange(cfg.num_layers))
+    return {"kv": attn_mod.init_paged_kv_cache(cfg, num_pages, page_size,
+                                               dtype)}
 
 
 def paged_decode_cache_specs():
-    def add_layer(spec):
-        return P(*((None,) + tuple(spec)))
-    return jax.tree.map(add_layer, {"kv": attn_mod.paged_kv_cache_specs()},
-                        is_leaf=lambda x: isinstance(x, P))
+    return {"kv": attn_mod.paged_kv_cache_specs()}
 
 
-def apply_block_decode_paged(params: Params, cfg: ModelConfig, x, cache,
-                             page_table, kv_len):
-    """One dense/moe block for one new token against the page pool."""
+def apply_block_decode_paged(params: Params, cfg: ModelConfig, x, caches,
+                             layer, page_table, kv_len):
+    """One dense/moe block for one new token against layer ``layer`` of
+    the page pools ``caches``."""
     h = apply_norm(params["attn_norm"], x, cfg.norm_type)
     a, kv = attn_mod.paged_decode_attention_step(
-        params["attn"], cfg, h, cache["kv"], page_table, kv_len)
+        params["attn"], cfg, h, caches["kv"], layer, page_table, kv_len)
     x = x + a
     x, _ = apply_ffn(params, cfg, x)
     return x, {"kv": kv}
 
 
-def apply_block_chunk_prefill(params: Params, cfg: ModelConfig, x, cache,
-                              dest_page, dest_off, page_list,
+def apply_block_chunk_prefill(params: Params, cfg: ModelConfig, x, caches,
+                              layer, dest_page, dest_off, page_list,
                               q_seg, kv_seg, q_pos, kv_pos):
-    """One dense/moe block for a packed batch of prefill CHUNKS against the
-    page pool (scatter new rows, attend each segment's prefix in place
-    through the page list — no per-layer gather)."""
+    """One dense/moe block for a packed batch of prefill CHUNKS against
+    layer ``layer`` of the page pools (scatter new rows, attend each
+    segment's prefix in place through the page list — no per-layer gather
+    copy)."""
     h = apply_norm(params["attn_norm"], x, cfg.norm_type)
     a, kv = attn_mod.chunk_prefill_attention_step(
-        params["attn"], cfg, h, cache["kv"], dest_page, dest_off,
+        params["attn"], cfg, h, caches["kv"], layer, dest_page, dest_off,
         page_list, q_seg, kv_seg, q_pos, kv_pos)
     x = x + a
     x, _ = apply_ffn(params, cfg, x)
     return x, {"kv": kv}
 
 
+def _apply_stack_paged(block, params: Params, cfg: ModelConfig, x, caches):
+    """Run ``block(p_l, x, caches, l)`` over the layers with the stacked
+    page pools as loop CARRY: each layer gets every layer's pool and its
+    index ``l``, writes its rows with one scatter at ``[l, page, offset]``
+    and reads them with one gather ``pool[l, pages]``, so no layer of the
+    pool is sliced out or written back (DESIGN.md §6). The scan takes
+    ``l`` from ``arange(L)`` beside the params; ``scan_layers=False``
+    unrolls the same block with a Python ``l``."""
+    L = jax.tree.leaves(caches)[0].shape[0]
+    if not cfg.scan_layers:
+        for l in range(L):
+            p_l = (params[l] if isinstance(params, list)
+                   else jax.tree.map(lambda p: p[l], params))
+            x, caches = block(p_l, x, caches, l)
+        return x, caches
+
+    def body(carry, inp):
+        return block(inp[0], *carry, inp[1]), None
+
+    (x, caches), _ = jax.lax.scan(body, (x, caches),
+                                  (params, jnp.arange(L)))
+    return x, caches
+
+
 def apply_stack_chunk_prefill(params: Params, cfg: ModelConfig, x, caches,
                               dest_page, dest_off, page_list,
                               q_seg, kv_seg, q_pos, kv_pos):
-    """Packed prefill chunks through all layers, threading per-layer pools.
-    The scatter map and kv page list are layer-invariant (one logical
-    sequence maps to the same pages in every layer's pool).
+    """Packed prefill chunks through all layers, the pools threaded as
+    carry (``_apply_stack_paged``). The scatter map and kv page list are
+    layer-invariant (one logical sequence maps to the same pages in every
+    layer's pool).
 
     Sequence-parallel contract (``cfg.sp_axis`` set, DESIGN.md §14): x,
     ``q_seg`` and ``q_pos`` are this shard's contiguous SLAB of the packed
@@ -347,57 +367,25 @@ def apply_stack_chunk_prefill(params: Params, cfg: ModelConfig, x, caches,
     while ``dest_page``/``dest_off``/``page_list``/``kv_seg``/``kv_pos``
     cover the FULL chunk on every shard (the pool is sp-replicated; the
     per-layer KV gather happens inside the attention step)."""
-    block = functools.partial(
-        apply_block_chunk_prefill, cfg=cfg, dest_page=dest_page,
-        dest_off=dest_off, page_list=page_list,
-        q_seg=q_seg, kv_seg=kv_seg, q_pos=q_pos, kv_pos=kv_pos)
-    if not cfg.scan_layers:
-        outs = []
-        L = jax.tree.leaves(caches)[0].shape[0]
-        for l in range(L):
-            p_l = jax.tree.map(lambda p: p[l], params) \
-                if not isinstance(params, list) else params[l]
-            c_l = jax.tree.map(lambda c: c[l], caches)
-            x, nc = block(p_l, x=x, cache=c_l)
-            outs.append(nc)
-        new_caches = jax.tree.map(lambda *cs: jnp.stack(cs), *outs)
-        return x, new_caches
+    def block(p_l, x, caches, l):
+        return apply_block_chunk_prefill(p_l, cfg, x, caches, l, dest_page,
+                                         dest_off, page_list, q_seg, kv_seg,
+                                         q_pos, kv_pos)
 
-    def body(x, inp):
-        p_l, cache_l = inp
-        x, new_cache = block(p_l, x=x, cache=cache_l)
-        return x, new_cache
-
-    x, new_caches = jax.lax.scan(body, x, (params, caches))
-    return x, new_caches
+    return _apply_stack_paged(block, params, cfg, x, caches)
 
 
 def apply_stack_decode_paged(params: Params, cfg: ModelConfig, x, caches,
                              page_table, kv_len):
-    """Scan a single token through all layers, threading per-layer pools.
-    ``page_table`` / ``kv_len`` are layer-invariant (one logical sequence
-    maps to the same pages in every layer's pool)."""
-    if not cfg.scan_layers:
-        outs = []
-        L = jax.tree.leaves(caches)[0].shape[0]
-        for l in range(L):
-            p_l = jax.tree.map(lambda p: p[l], params) \
-                if not isinstance(params, list) else params[l]
-            c_l = jax.tree.map(lambda c: c[l], caches)
-            x, nc = apply_block_decode_paged(p_l, cfg, x, c_l,
-                                             page_table, kv_len)
-            outs.append(nc)
-        new_caches = jax.tree.map(lambda *cs: jnp.stack(cs), *outs)
-        return x, new_caches
+    """A single token through all layers, the pools threaded as carry
+    (``_apply_stack_paged``). ``page_table`` / ``kv_len`` are
+    layer-invariant (one logical sequence maps to the same pages in every
+    layer's pool)."""
+    def block(p_l, x, caches, l):
+        return apply_block_decode_paged(p_l, cfg, x, caches, l, page_table,
+                                        kv_len)
 
-    def body(x, inp):
-        p_l, cache_l = inp
-        x, new_cache = apply_block_decode_paged(p_l, cfg, x, cache_l,
-                                                page_table, kv_len)
-        return x, new_cache
-
-    x, new_caches = jax.lax.scan(body, x, (params, caches))
-    return x, new_caches
+    return _apply_stack_paged(block, params, cfg, x, caches)
 
 
 def apply_stack_decode(params: Params, cfg: ModelConfig, x, caches, kv_len):

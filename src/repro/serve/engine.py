@@ -562,8 +562,9 @@ class ServingEngine:
         """shard_map-wrap the device step functions over the serving mesh
         (1-D ``("tp",)``, or 2-D ``("sp", "tp")`` when sp > 1).
 
-        Per-shard layout: pool leaves (L, hkv, pages, page_size, hd) and
-        packed-prefill leaves (L, 1, hkv, S, hd) shard their KV-HEAD axis;
+        Per-shard layout: pool leaves (L, pages, page_size, hkv*hd) shard
+        their lane axis (whole heads, head-major), packed-prefill leaves
+        (L, 1, hkv, S, hd) their KV-HEAD axis;
         tokens, page tables, kv lengths, scatter indices, and logits are
         replicated (``P()``) — the host allocator's page indices are valid
         on every shard, and replicated logits make sampling a plain jit
@@ -586,7 +587,7 @@ class ServingEngine:
         chunk step, whose suffix machinery is exact at start=0."""
         mesh = self.mesh
         pool_spec = jax.tree.map(
-            lambda _: P(None, "tp", None, None, None), self.state["caches"])
+            lambda _: P(None, None, None, "tp"), self.state["caches"])
         state_spec = {"caches": pool_spec, "page_table": P(), "kv_len": P()}
         self._state_spec = state_spec
         sm = self._shard_model
@@ -688,9 +689,10 @@ class ServingEngine:
             if self.sp > 1:
                 raise ValueError(
                     "the packed->pool scatter is an sp=1-only path")
+            cfg = self.model.cfg
             packed = jax.tree.map(
-                lambda c: jnp.zeros((c.shape[0], 1, c.shape[1], S,
-                                     c.shape[4]), c.dtype),
+                lambda c: jnp.zeros((c.shape[0], 1, cfg.num_kv_heads, S,
+                                     cfg.head_dim), c.dtype),
                 self.state["caches"])
             dest_page = jnp.full((S,), self.kv.num_pages, jnp.int32)
             dest_off = jnp.zeros((S,), jnp.int32)

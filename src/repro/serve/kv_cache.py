@@ -3,7 +3,7 @@
 FlashAttention processes attention in SRAM-sized tiles so HBM traffic
 scales with the tiles actually touched; the serving-side dual is to
 allocate cache memory in the same tiles. The device state is a shared page
-pool — per-layer ``(L, hkv, num_pages, page_size, hd)`` arrays — and each
+pool — lane-dense ``(L, num_pages, page_size, hkv*hd)`` arrays — and each
 sequence owns a *page table* mapping its logical kv blocks (positions
 ``[t*page_size, (t+1)*page_size)``) to physical pool pages. Consequences:
 
@@ -410,14 +410,16 @@ def paged_prefix_lists(tables: list[list[int]], spans: list[int],
 def scatter_packed_segments(pool_caches, packed_caches, dest_page, dest_off):
     """Scatter a packed prefill's K/V rows straight into pool pages.
 
-    pool leaves: (L, hkv, num_pages, page_size, hd); packed leaves
+    pool leaves: (L, num_pages, page_size, hkv*hd); packed leaves
     (L, 1, hkv, S, hd); dest_page/dest_off: (S,) int32 with out-of-bounds
     page ids for padding rows (mode='drop'). Jitted by the engine with the
     pool donated — one in-place HBM pass per admitted batch.
     """
     def scat(pool, packed):
-        src = packed[:, 0].astype(pool.dtype)            # (L, hkv, S, hd)
-        return pool.at[:, :, dest_page, dest_off, :].set(src, mode="drop")
+        L, _, hkv, S, hd = packed.shape
+        src = packed[:, 0].transpose(0, 2, 1, 3).reshape(L, S, hkv * hd)
+        return pool.at[:, dest_page, dest_off, :].set(src.astype(pool.dtype),
+                                                      mode="drop")
 
     with scopes.named(scopes.KV_WRITE):
         return jax.tree.map(scat, pool_caches, packed_caches)

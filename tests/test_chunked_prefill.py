@@ -4,6 +4,8 @@ token-identity across chunk sizes, decode/prefill interleaving, preemption
 at chunk boundaries (greedy AND seeded sampling), and sampling-key
 persistence."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -135,6 +137,23 @@ def _run(model, params, *, chunk=None, budget=None, slots=3, n_new=6,
     done = eng.run()
     assert len(done) == len(PROMPTS)
     return {r.rid: r.output for r in done}, eng
+
+
+@pytest.mark.parametrize("chunk", [None, 8])
+def test_unrolled_layers_match_scanned_stack(setup, chunk):
+    """``scan_layers=False`` runs the paged decode and chunk-prefill
+    blocks with a Python layer index over the same carried pool: the same
+    tokens and the same pool rows as the scanned stack."""
+    cfg, model, params = setup
+    unrolled = build_model(dataclasses.replace(cfg, scan_layers=False))
+    blocks = [jax.tree.map(lambda p: p[l], params["blocks"])
+              for l in range(cfg.num_layers)]
+    out_scan, e_scan = _run(model, params, chunk=chunk)
+    out_unr, e_unr = _run(unrolled, {**params, "blocks": blocks}, chunk=chunk)
+    assert out_unr == out_scan
+    for got, want in zip(jax.tree.leaves(e_unr.state["caches"]),
+                         jax.tree.leaves(e_scan.state["caches"])):
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
 
 
 def test_chunked_token_identical_across_chunk_sizes(setup):

@@ -27,6 +27,13 @@ from repro.serve import kv_cache as kvc
 # configs is ~1e-6 on O(1) values; anything past 1e-4 is a real bug.
 INV = dict(rtol=1e-4, atol=1e-5)
 
+
+def _lane_dense(pool):
+    """A head-major (hkv, P, ps, d) kernel pool as the one-layer lane-dense
+    (1, P, ps, hkv*d) pool the paged attention entry points read."""
+    hkv, num_pages, ps, d = pool.shape
+    return pool.transpose(1, 2, 0, 3).reshape(1, num_pages, ps, hkv * d)
+
 BLOCKS = [(64, 64), (32, 128), (128, 32), (128, 128), (256, 256),
           (64, 256), (None, None)]
 
@@ -183,7 +190,8 @@ class TestLoopOrderInvariance:
         oracle_spec = AttentionSpec(impl="chunked", causal=True)
 
         def oracle(q, kp, vp):
-            return paged_prefill_attention(q, kp, vp, arrs["page_list"],
+            return paged_prefill_attention(q, _lane_dense(kp), _lane_dense(vp),
+                                           0, arrs["page_list"],
                                            oracle_spec, **common)
 
         for got, want in zip(_fwd_and_grads(in_place, q, k_pool, v_pool),
@@ -226,7 +234,8 @@ class TestDecodeGeometryInvariance:
             np.asarray(vc).reshape(3, hkv, T, ps, d).transpose(1, 0, 2, 3, 4))
         o = flash_decode_paged(q, kp, vp, table, kv_len, num_splits=splits)
         xla = paged_decode_attention(
-            q, kp, vp, table, kv_len, AttentionSpec(use_decode_kernel=False))
+            q, _lane_dense(kp), _lane_dense(vp), 0, table, kv_len,
+            AttentionSpec(use_decode_kernel=False))
         np.testing.assert_allclose(o, xla, **INV)
 
     def test_auto_geometry_matches_every_pinned_geometry(self):

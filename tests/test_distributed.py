@@ -373,7 +373,7 @@ def test_tp_page_pool_slicing_property():
         for sh in l4.addressable_shards:
             np.testing.assert_array_equal(np.asarray(sh.data),
                                           glob4[sh.index])
-            assert sh.data.shape[1] == glob4.shape[1] // 4
+            assert sh.data.shape[-1] == glob4.shape[-1] // 4
     print("TP_POOL_SLICING_OK")
     """
     assert "TP_POOL_SLICING_OK" in run_devices(body)

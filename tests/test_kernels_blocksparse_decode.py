@@ -226,10 +226,13 @@ def test_paged_decode_xla_parity_and_dispatch():
     from repro.core.attention import AttentionSpec, paged_decode_attention
     b, hq, hkv, d, ps, T, P = 2, 4, 2, 16, 8, 4, 16
     q, kp, vp, table, kvl = _paged_case(1, b, hq, hkv, d, ps, T, P, [19, 32])
-    o_xla = paged_decode_attention(q, kp, vp, table, kvl,
+    # the entry point reads the lane-dense (L, P, ps, hkv*d) pool
+    kl, vl = (x.transpose(1, 2, 0, 3).reshape(1, P, ps, hkv * d)
+              for x in (kp, vp))
+    o_xla = paged_decode_attention(q, kl, vl, 0, table, kvl,
                                    AttentionSpec(use_decode_kernel=False))
     o_ker = paged_decode_attention(
-        q, kp, vp, table, kvl,
+        q, kl, vl, 0, table, kvl,
         AttentionSpec(use_decode_kernel=True, num_decode_splits=2))
     np.testing.assert_allclose(o_ker, o_xla, **TOL)
     np.testing.assert_allclose(o_xla, _paged_oracle(q, kp, vp, table, kvl),

@@ -294,9 +294,9 @@ def test_paged_state_specs_match_state_structure(setup):
             == jax.tree.structure(specs,
                                   is_leaf=lambda x: not isinstance(x, dict)))
     pool_spec = specs["caches"]["kv"]["k"]
-    pool_shape = state["caches"]["kv"]["k"].shape  # (L, hkv, P, ps, hd)
+    pool_shape = state["caches"]["kv"]["k"].shape  # (L, P, ps, hkv*hd)
     assert len(pool_spec) == len(pool_shape)
-    assert pool_spec[2] == "kv_seq"
+    assert pool_spec[1] == "kv_seq"
 
 
 # ---------------------------------------------------------------------------
@@ -318,8 +318,8 @@ def test_free_page_garbage_cannot_leak_into_outputs(setup):
     free = np.asarray([p for p in range(eng.kv.num_pages) if p not in used])
 
     def poison(leaf):
-        # leaf: (L, hkv, num_pages, page_size, hd)
-        return leaf.at[:, :, jnp.asarray(free)].set(7.7e4)
+        # leaf: (L, num_pages, page_size, hkv*hd)
+        return leaf.at[:, jnp.asarray(free)].set(7.7e4)
 
     caches = eng.state["caches"]
     caches["kv"] = {k: poison(v) for k, v in caches["kv"].items()}

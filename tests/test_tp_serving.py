@@ -147,7 +147,7 @@ def test_per_shard_kv_bytes_shrink(setup):
     assert e4.per_shard_cache_bytes() * 4 == e4.cache_bytes()
     leaf = jax.tree.leaves(e4.state["caches"])[0]
     assert len(leaf.sharding.device_set) == 4
-    assert leaf.addressable_shards[0].data.shape[1] == leaf.shape[1] // 4
+    assert leaf.addressable_shards[0].data.shape[-1] == leaf.shape[-1] // 4
 
 
 @pytest.mark.multidevice

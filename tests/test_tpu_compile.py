@@ -13,15 +13,20 @@ worker imports every test file. All such tests live in this one file.
 
 from __future__ import annotations
 
+import dataclasses
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_config
 from repro.kernels.flash_decode import flash_decode, flash_decode_paged
 from repro.kernels.ops import flash_attention, flash_prefill_paged
+from repro.models import build_model
 
 BF16 = jnp.bfloat16
 I32 = jnp.int32
@@ -126,3 +131,97 @@ def test_flash_decode_paged_pool(one_chip, page_size):
         lambda q, kp, vp, table, kv_len: flash_decode_paged(
             q, kp, vp, table, kv_len, interpret=False), *args)
     assert "tpu_custom_call" in text
+
+
+# ---------------------------------------------------------------------------
+# paged step programs: the page pool is updated in place, never copied
+# ---------------------------------------------------------------------------
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%(\S+) .*\{$")
+_INSTRUCTION = re.compile(
+    r"^\s+(ROOT )?%(\S+) = (\w+)\[([\d,]*)\]\S* ([\w-]+)\((%[\w.-]+)?(.*)")
+_FUSED = re.compile(r" fusion\(.*calls=%([\w.-]+)")
+_MOVES = ("copy", "dynamic-slice", "dynamic-update-slice")
+
+
+def _materialized_moves(hlo: str) -> list[tuple[str, str, int]]:
+    """(name, opcode, bytes) of every copy, dynamic-slice and
+    dynamic-update-slice in an optimised HLO module that writes a buffer
+    of its own: an instruction outside every fusion body, or a fusion
+    whose root (through bitcasts) is one. Inside a fusion they only
+    address the operands of the fused computation."""
+    comps: dict[str, dict] = {}
+    cur: dict = {}
+    for line in hlo.splitlines():
+        if m := _COMPUTATION.match(line):
+            cur = comps.setdefault(m.group(1), {})
+        elif m := _INSTRUCTION.match(line):
+            root, name, dtype, dims, op, arg, rest = m.groups()
+            bits = re.sub(r"\D", "", dtype)         # bf16 -> 16; pred -> 8
+            nbytes = max(1, int(bits or 8) // 8)
+            for d in filter(None, dims.split(",")):
+                nbytes *= int(d)
+            cur[name] = (op, nbytes, (arg or "")[1:], rest)
+            if root:
+                cur[None] = name
+    fused = set(_FUSED.findall(hlo))
+
+    def root_op(comp):
+        instrs = comps[comp]
+        op, _, arg, _ = instrs[instrs[None]]
+        while op == "bitcast" and arg in instrs:
+            op, _, arg, _ = instrs[arg]
+        return op
+
+    moves = []
+    for cname, instrs in comps.items():
+        if cname in fused:
+            continue
+        for name, (op, nbytes, _, rest) in (
+                (k, v) for k, v in instrs.items() if k is not None):
+            if op == "fusion":
+                op = root_op(re.search(r"calls=%([\w.-]+)", rest).group(1))
+            if op in _MOVES:
+                moves.append((name, op, nbytes))
+    return moves
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk_paged"])
+def test_paged_steps_update_the_pool_in_place(one_chip, program):
+    """granite-3-2b at its published widths (4 layers), 8 lanes over 2048
+    pages of 16 rows, the pool donated as the engine donates it: the
+    decode step, and a 512-row chunk over a 4096-row prefix. No copy,
+    dynamic-slice or dynamic-update-slice writes a tensor of one layer's
+    K pool or more, and the program's temporaries stay under one layer's
+    K pool — the layer loop writes and reads the lane-dense pool in place
+    with one scatter and one gather."""
+    lanes, pages, page_size, capacity, chunk = 8, 2048, 16, 4096, 512
+    cfg = dataclasses.replace(get_config("granite-3-2b"), num_layers=4)
+    model = build_model(cfg)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    state = on_chip(jax.eval_shape(lambda: model.init_paged_decode_state(
+        lanes, pages, page_size, capacity // page_size)))
+    if program == "decode_step":
+        args = (params, state, *_shapes(one_chip, ((lanes,), I32)))
+        fn = jax.jit(model.decode_step, donate_argnums=(1,))
+    else:
+        rows = {"tokens": (1, chunk), "q_segment_ids": (1, chunk),
+                "q_positions": (1, chunk), "kv_segment_ids": (1, capacity),
+                "kv_positions": (1, capacity), "dest_page": (chunk,),
+                "dest_off": (chunk,), "page_list": (1, capacity // page_size)}
+        batch = {k: jax.ShapeDtypeStruct(v, I32, sharding=one_chip)
+                 for k, v in rows.items()}
+        args = (params, batch, state["caches"])
+        fn = jax.jit(model.prefill_chunk_paged, donate_argnums=(2,))
+    compiled = fn.lower(*args).compile()
+    k_pool = state["caches"]["kv"]["k"]
+    layer_pool = math.prod(k_pool.shape[1:]) * k_pool.dtype.itemsize
+    big = [m for m in _materialized_moves(compiled.as_text())
+           if m[2] >= layer_pool]
+    assert big == []
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_pool
