@@ -18,7 +18,8 @@ class Run:
     cell: Any
     seed: int
     seconds: float
-    peaks: dict
+    peaks: dict                             # one chip's
+    chips: int = 1
     setup_s: float = 0.0
     window: tuple[float, float] = (0.0, 0.0)
     attempted: int = 0

@@ -30,6 +30,18 @@ def altered_token():
     return _swap(sampling, "sample_tokens", broken)
 
 
+def no_exchange():
+    """Sharded serving: the two projection all-reduces of every layer are
+    left out, so each chip carries on with its own partial sums."""
+    from repro.models import attention_layer
+
+    def broken(orig):
+        def reduce(y, cfg):
+            return y
+        return reduce
+    return _swap(attention_layer, "_tp_reduce", broken)
+
+
 def _train_step_fault(wrap):
     from repro import train
 
@@ -63,3 +75,12 @@ def half_batch():
 
 SERVE = {"altered_token": altered_token}
 TRAIN = {"unchanged_state": unchanged_state, "half_batch": half_batch}
+SHARDED = {"no_exchange": no_exchange}      # cells on more than one chip
+
+
+def planted(cell) -> dict:
+    """The faults that ``cell`` can have."""
+    out = dict(SERVE if cell.mix["kind"] == "serve" else TRAIN)
+    if cell.chips > 1:
+        out.update(SHARDED)
+    return out

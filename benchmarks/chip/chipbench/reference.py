@@ -4,9 +4,11 @@ Straightforward ``jax.numpy``: no kernels, no cache, no batching, no
 imports from the program. Matmuls run at ``highest`` precision so that
 float32 means float32 on a TPU. Weights are the harness's (``weights.py``),
 read by name from the program's layout: ``embed`` (V, d), tied as the LM
-head; ``final_norm``; ``blocks`` stacked over layers with ``attn_norm``,
-``attn.{wq,wk,wv,wo}``, ``mlp_norm`` and ``mlp.{w_gate,w_up,w_down}``
-(SwiGLU) or ``mlp.{w_up,w_down}`` (GELU, tanh form).
+head unless the configuration says ``"tie_embeddings": false``, when the
+head is ``lm_head`` (d, V); ``final_norm``; ``blocks`` stacked over layers
+with ``attn_norm``, ``attn.{wq,wk,wv,wo}``, ``mlp_norm`` and
+``mlp.{w_gate,w_up,w_down}`` (SwiGLU) or ``mlp.{w_up,w_down}`` (GELU,
+tanh form). Weights laid out over several chips are read as they lie.
 
 The block: pre-norm residual attention then MLP; rotary embeddings on the
 two halves of each head (``x1 cos - x2 sin, x1 sin + x2 cos``) with
@@ -45,13 +47,16 @@ class Arch:
     norm_eps: float
     mlp: str             # "swiglu" | "gelu_tanh"
     rope_theta: float
+    tie_embeddings: bool = True
 
 
 def arch_from_config(config: dict) -> Arch:
     """The reference's reading of a configuration file (``"reference"``
-    block: the block's equations as the configuration states them)."""
+    block: the block's equations as the configuration states them; a head
+    is tied unless the block says ``"tie_embeddings": false``)."""
     r = config["reference"]
-    return Arch(**{f.name: r[f.name] for f in dataclasses.fields(Arch)})
+    return Arch(**{f.name: r[f.name] for f in dataclasses.fields(Arch)
+                   if f.name in r})
 
 
 # --------------------------------------------------------------- numerics
@@ -143,10 +148,18 @@ def _segment_positions(seg):
     return idx - jax.lax.cummax(jnp.where(start, idx, 0))
 
 
+def _head_matrix(params, arch: Arch):
+    """The LM head and the einsum that applies it to (s, d) rows."""
+    if arch.tie_embeddings:
+        return params["embed"], "sd,vd->sv"
+    return params["lm_head"], "sd,dv->sv"
+
+
 def _head(params, x, arch: Arch, precision: str):
     dt = _compute_dtype(precision)
     h = _norm(x, params["final_norm"], arch, dt)
-    return _mm(h, params["embed"], precision, "sd,vd->sv").astype(jnp.float32)
+    w, spec = _head_matrix(params, arch)
+    return _mm(h, w, precision, spec).astype(jnp.float32)
 
 
 # ---------------------------------------------------------------- serving
@@ -157,19 +170,63 @@ def _serve_layer(p, x, pos, seg, arch, precision):
         return _block(p, x, pos, seg, arch, precision)
 
 
+VOCAB_BLOCK = 16384     # head columns upcast at a time
+ROW_BUCKET = 128        # rows compared are padded to a multiple: few shapes
+
+
 @functools.partial(jax.jit, static_argnames=("arch", "precision"))
-def _serve_head(params, x, arch, precision):
+def _final_rows(final_norm, x, rows, arch, precision):
+    """The final norm over every row, then the rows asked for. A control in
+    fp8 rounds the normed rows as one tensor first, as ``_mm`` would."""
+    h = _norm(x, final_norm, arch, _compute_dtype(precision))
+    if precision == "fp8":
+        h = _fp8(h.astype(jnp.float32))
+    return h[rows]
+
+
+@jax.jit
+def _fp8_scale(w):
+    return jnp.maximum(jnp.max(jnp.abs(w.astype(jnp.float32))), 1e-30) / 448.0
+
+
+@functools.partial(jax.jit, static_argnames=("spec", "precision"))
+def _head_block(h, w, w_scale, spec, precision):
+    """Logits of rows ``h`` over one block of the head's columns; in fp8
+    the block is rounded with the whole head's scale ``w_scale``."""
+    dt = _compute_dtype(precision)
+    if precision == "fp8":
+        w = (w.astype(jnp.float32) / w_scale).astype(
+            jnp.float8_e4m3fn).astype(jnp.float32) * w_scale
     with jax.default_matmul_precision("highest"):
-        return _head(params, x, arch, precision)
+        out = jnp.einsum(spec, h.astype(dt), w.astype(dt),
+                         preferred_element_type=jnp.float32)
+    return out.astype(dt).astype(jnp.float32)
+
+
+def _head_rows(params, x, rows, arch: Arch, precision: str):
+    """(R, V) float32 logits of ``rows`` of ``x``, the head applied in
+    blocks of about ``VOCAB_BLOCK`` columns so that only one block of it is
+    upcast at a time."""
+    h = _final_rows(params["final_norm"], x, rows, arch, precision)
+    w, spec = _head_matrix(params, arch)
+    V = arch.vocab
+    scale = _fp8_scale(w) if precision == "fp8" else jnp.float32(1.0)
+    nb = -(-V // VOCAB_BLOCK)
+    edges = [V * i // nb for i in range(nb + 1)]
+    blocks = []
+    for a, b in zip(edges[:-1], edges[1:]):
+        wb = w[a:b] if arch.tie_embeddings else w[:, a:b]
+        blocks.append(_head_block(h, wb, scale, spec, precision))
+    return jnp.concatenate(blocks, axis=-1)
 
 
 def serve_logits(params, tokens, arch: Arch, precision: str = "f32",
-                 pad_to: int = 1024):
-    """(S,) tokens -> (S', V) float32 logits on the device of a causal
-    forward pass, layer by layer so that only one layer's weights are upcast
-    at a time. The sequence is padded at its end to ``S'``, a multiple of
-    ``pad_to`` (causal rows never see the padding), so that few shapes
-    compile; rows past ``S`` are padding."""
+                 pad_to: int = 1024, rows=None):
+    """(S,) tokens -> (R, V) float32 logits on the device of a causal
+    forward pass at ``rows`` (default: every row), layer by layer so that
+    only one layer's weights are upcast at a time. The sequence is padded at
+    its end to ``S'``, a multiple of ``pad_to`` (causal rows never see the
+    padding), so that few shapes compile; rows past ``S`` are padding."""
     n = len(tokens)
     S = n + (-n) % pad_to
     toks = np.zeros((S,), np.int32)
@@ -180,7 +237,12 @@ def serve_logits(params, tokens, arch: Arch, precision: str = "f32",
     seg = jnp.zeros((S,), jnp.int32)
     for i in range(arch.layers):
         x = _serve_layer(_layer(params, i), x, pos, seg, arch, precision)
-    return _serve_head(params, x, arch, precision)
+    if rows is None:
+        rows = np.arange(S)
+    rows = np.asarray(rows, np.int32)
+    R = len(rows)
+    padded = np.pad(rows, (0, (-R) % ROW_BUCKET), mode="edge")
+    return _head_rows(params, x, jnp.asarray(padded), arch, precision)[:R]
 
 
 @jax.jit

@@ -214,9 +214,7 @@ def read_scopes(trace: str, scopes: tuple[str, ...]) -> ScopeSummary:
     for plane in ProfileData.from_file(trace).planes:
         if plane.name.startswith("/device:"):
             lines = {line.name: line for line in plane.lines}
-            # "%fusion.3 = f32[...] ..." -> "%fusion.3", as trace_reduce
-            dev_ops.append([Event(e.name.split(" = ", 1)[0], e.start_ns,
-                                  e.end_ns) for e in
+            dev_ops.append([trace_reduce.op_event(e) for e in
                             getattr(lines.get("XLA Ops"), "events", ())])
             dev_mods.append([Event(e.name, e.start_ns, e.end_ns) for e in
                              getattr(lines.get("XLA Modules"), "events", ())])

@@ -13,6 +13,7 @@ longest one, is checked against the plain float32 reference.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import gc
 import time
@@ -92,16 +93,21 @@ def run_serve(cell, seed: int, seconds: float, trace: bool, run: Run,
     from repro.serve import ServingEngine
 
     mix, E = cell.mix, cell.mix["engine"]
+    tp = int(E.get("tp", 1))
+    if tp != cell.chips:
+        raise ValueError(f"{cell.name}: the mix serves at tp={tp}, the cell "
+                         f"asks for {cell.chips} chips")
     model = program_model(cell.config)
     cfg = model.cfg
     run.dims = dims_of(cfg)
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    params = make_params(shapes, seed)
-    eng = ServingEngine(model, params, num_slots=E["lanes"],
-                        capacity=E["capacity"], page_size=E["page_size"],
-                        chunk_size=E["chunk_size"],
-                        prefill_bucket=E["prefill_bucket"],
-                        num_pages=E.get("num_pages"), trace=trace)
+    params = make_params(shapes, seed, serve_shardings(model, tp))
+    with _pool_staging(tp):
+        eng = ServingEngine(model, params, num_slots=E["lanes"],
+                            capacity=E["capacity"], page_size=E["page_size"],
+                            chunk_size=E["chunk_size"],
+                            prefill_bucket=E["prefill_bucket"],
+                            num_pages=E.get("num_pages"), tp=tp, trace=trace)
     run.notes["num_pages"] = eng.kv.num_pages
     run.notes["warm_shapes"] = warm_step_shapes(eng, mix)
     sched = traffic.serve_schedule(mix, seed, seconds, cfg.vocab_size)
@@ -176,6 +182,9 @@ def run_serve(cell, seed: int, seconds: float, trace: bool, run: Run,
     run.requests = recs
     run.steps = steps
     run.notes["gap_prefill_share"] = prefill_gap_share(run)
+    inside = [st for st in steps if t0 <= st[0] and st[1] <= t1]
+    run.notes["window_tokens"] = {"prefill": sum(st[2] for st in inside),
+                                  "generated": sum(st[3] for st in inside)}
     run.engine_events = _engine_events(eng) if trace else None
     in_window = window_requests(run)
     run.attempted = len(in_window)
@@ -187,6 +196,27 @@ def run_serve(cell, seed: int, seconds: float, trace: bool, run: Run,
     gc.collect()
     run.check_inputs = sample
     run.params = params
+
+
+def serve_shardings(model, tp: int):
+    """The layout the engine serves the weights from at ``tp`` (``None``
+    at 1): the model's logical specs under the engine's tensor-parallel
+    rules, over its ``("tp",)`` mesh."""
+    if tp == 1:
+        return None
+    from repro.distributed import meshes, sharding
+    return sharding.resolve_tree(model.param_specs(), meshes.tp_mesh(tp),
+                                 sharding.tp_serve_rules())
+
+
+def _pool_staging(tp: int):
+    """Where the engine builds its zero page pool before it shards it over
+    the mesh. At ``tp`` > 1 that is the host's memory: the engine makes the
+    whole pool on the default device first, which beside that chip's share
+    of the weights would not fit."""
+    if tp == 1:
+        return contextlib.nullcontext()
+    return jax.default_device(jax.devices("cpu")[0])
 
 
 def _engine_events(eng) -> list[dict]:
@@ -224,15 +254,17 @@ def logit_gap_readings(params, sample, arch, control: str | None = None):
     prog, ctrl = [], []
     for prompt, served in sample:
         seq = prompt + served[:-1]
-        rows = jnp.arange(len(prompt) - 1, len(seq), dtype=jnp.int32)
-        ref = reference.serve_logits(params, seq, arch)
+        rows = np.arange(len(prompt) - 1, len(seq))
+        at = jnp.arange(len(rows), dtype=jnp.int32)
+        ref = reference.serve_logits(params, seq, arch, rows=rows)
         prog.append(np.asarray(reference.logit_gaps(
-            ref, rows, jnp.asarray(served, jnp.int32))))
+            ref, at, jnp.asarray(served, jnp.int32))))
         if control is not None:
-            low = reference.serve_logits(params, seq, arch, precision=control)
-            pick = reference.row_argmax(low, rows)
+            low = reference.serve_logits(params, seq, arch, precision=control,
+                                         rows=rows)
+            pick = reference.row_argmax(low, at)
             del low
-            ctrl.append(np.asarray(reference.logit_gaps(ref, rows, pick)))
+            ctrl.append(np.asarray(reference.logit_gaps(ref, at, pick)))
         del ref
     cat = lambda xs: np.concatenate(xs) if xs else np.zeros((0,))
     return cat(prog), cat(ctrl)

@@ -9,6 +9,10 @@ named after the jitted function (``jit_decode_step(12)`` ->
 averaged over the devices that ran anything; an idle gap is a stretch of
 the window with no op on the device, labelled by the innermost harness
 annotation (``engine.step``, ``train.sync``, ...) that covers its midpoint.
+Collective time is the union of the intervals of the ops whose HLO kind is
+a collective (``all-reduce``, ``all-gather``, ``reduce-scatter``,
+``collective-permute``, ``all-to-all``, and their ``-start`` / ``-done``
+halves), averaged the same way.
 """
 
 from __future__ import annotations
@@ -22,6 +26,9 @@ import re
 WINDOW = "bench.window"
 HARNESS_SPANS = ("traffic.submit", "engine.step", "client.collect",
                  "data.batch", "train.step", "train.sync")
+COLLECTIVE = re.compile(r"^[%_]?(all-reduce|all-gather|reduce-scatter|"
+                        r"collective-permute|all-to-all)(-start|-done)?\b")
+_OPCODE = re.compile(r"(?:^|\s)([a-z][a-z0-9-]*)\(")
 
 
 @dataclasses.dataclass
@@ -29,6 +36,7 @@ class Event:
     name: str
     start: float             # ns, trace clock
     end: float
+    kind: str = ""           # HLO opcode of a device op, where known
 
 
 @dataclasses.dataclass
@@ -40,6 +48,26 @@ class TraceSummary:
     ops: dict[str, float]                # op name -> device seconds
     idle_by_span: dict[str, float]       # host span -> idle seconds
     devices: int
+    collective_s: float = 0.0            # device seconds in collectives
+    collectives: dict[str, float] = dataclasses.field(default_factory=dict)
+
+
+def op_kind(hlo: str) -> str:
+    """The opcode of an op from its HLO text after ``" = "``
+    (``"f32[8]{0} all-reduce(f32[8]{0} %x), ..."`` -> ``"all-reduce"``)."""
+    m = _OPCODE.search(hlo)
+    return m.group(1) if m else ""
+
+
+def op_event(e) -> Event:
+    """A trace's device op event, ``"%fusion.3 = f32[...] fusion(...)"``,
+    as ``Event("%fusion.3", ..., kind="fusion")``."""
+    name, _, hlo = e.name.partition(" = ")
+    return Event(name, e.start_ns, e.end_ns, op_kind(hlo))
+
+
+def is_collective(e: Event) -> bool:
+    return bool(COLLECTIVE.match(e.kind) or COLLECTIVE.match(e.name))
 
 
 def program_name(module: str) -> str:
@@ -83,7 +111,7 @@ def summarize(window: tuple[float, float], device_ops: list[list[Event]],
     spans = sorted(host_spans, key=lambda h: h.start)
     starts = [h.start for h in spans]
     busy, gaps_idle, ops, programs, calls = [], {}, {}, {}, {}
-    used = 0
+    used, coll, colls = 0, 0.0, {}
     for dev_ops, dev_mods in zip(device_ops, device_modules):
         clipped = _clip(dev_ops, w0, w1)
         if not clipped:
@@ -91,6 +119,12 @@ def summarize(window: tuple[float, float], device_ops: list[list[Event]],
         used += 1
         length, merged = union_length(clipped)
         busy.append(length)
+        mine = [e for e in dev_ops if is_collective(e)]
+        coll += union_length(_clip(mine, w0, w1))[0]
+        for e in mine:
+            d = min(e.end, w1) - max(e.start, w0)
+            if d > 0:
+                colls[e.name] = colls.get(e.name, 0.0) + d * 1e-9
         for e in dev_ops:
             d = min(e.end, w1) - max(e.start, w0)
             if d > 0:
@@ -111,7 +145,9 @@ def summarize(window: tuple[float, float], device_ops: list[list[Event]],
         window_s=(w1 - w0) * 1e-9, busy_s=sum(busy) * 1e-9 / n,
         programs={k: v / n for k, v in programs.items()},
         program_calls=calls, ops={k: v / n for k, v in ops.items()},
-        idle_by_span={k: v / n for k, v in gaps_idle.items()}, devices=used)
+        idle_by_span={k: v / n for k, v in gaps_idle.items()}, devices=used,
+        collective_s=coll * 1e-9 / n,
+        collectives={k: v / n for k, v in colls.items()})
 
 
 def read_trace(trace_dir: str) -> TraceSummary:
@@ -131,9 +167,8 @@ def read_trace(trace_dir: str) -> TraceSummary:
         if plane.name.startswith("/device:"):
             ops, mods = [], []
             for line in plane.lines:
-                if line.name == "XLA Ops":       # "%fusion.3 = f32[...] ..." -> "%fusion.3"
-                    ops = [Event(e.name.split(" = ", 1)[0], e.start_ns, e.end_ns)
-                           for e in line.events]
+                if line.name == "XLA Ops":
+                    ops = [op_event(e) for e in line.events]
                 elif line.name == "XLA Modules":
                     mods = [Event(e.name, e.start_ns, e.end_ns) for e in line.events]
             dev_ops.append(ops)

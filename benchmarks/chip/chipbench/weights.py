@@ -6,7 +6,9 @@ layout and shapes are the program's (``jax.eval_shape`` of its init); every
 leaf is filled here: norm scales ``w`` with ones, biases ``b`` with zeros,
 every matrix with a normal truncated at two standard deviations, of
 standard deviation ``1 / sqrt(fan_in)`` (``fan_in`` = the second-to-last
-dimension; a stacked-layer leaf keeps its leading layer axis).
+dimension; a stacked-layer leaf keeps its leading layer axis). Given
+shardings, the call makes each leaf directly in its layout, with the same
+values.
 """
 
 from __future__ import annotations
@@ -20,9 +22,10 @@ def _leaf_name(path) -> str:
     return str(getattr(last, "key", getattr(last, "name", last)))
 
 
-def make_params(shapes, seed: int):
+def make_params(shapes, seed: int, shardings=None):
     """``shapes``: a pytree of ``jax.ShapeDtypeStruct``. Returns arrays of
-    the same shapes and dtypes."""
+    the same shapes and dtypes; with ``shardings`` (a matching pytree of
+    ``jax.sharding.Sharding``) each leaf is laid out as it says."""
     leaves, treedef = jax.tree_util.tree_flatten_with_path(shapes)
 
     def build(key):
@@ -41,4 +44,5 @@ def make_params(shapes, seed: int):
             out.append(x.astype(s.dtype))
         return jax.tree_util.tree_unflatten(treedef, out)
 
-    return jax.jit(build)(jax.random.key(seed % (1 << 63)))
+    return jax.jit(build, out_shardings=shardings)(
+        jax.random.key(seed % (1 << 63)))

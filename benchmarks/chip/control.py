@@ -47,7 +47,7 @@ def main():
     enable_compile_cache()
     cell = manifest.load_cell(args.workload)
     low = cell.config["control_precision"]
-    planted = faults.SERVE if cell.mix["kind"] == "serve" else faults.TRAIN
+    planted = faults.planted(cell)
     for seed in (int(s) for s in args.seeds.split(",")):
         out = bench.run_cell(cell, seed, args.seconds, False, peaks, control=low)
         print(json.dumps({"seed": seed, "reading": "program",

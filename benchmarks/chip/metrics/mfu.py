@@ -1,8 +1,8 @@
 """Whole step: model FLOPs of all the work done in the window over the
-window times the chip's peak (%). Serving counts every decode and prefill
-call that ran inside the window; training counts every step started in it
-(forward and backward, recomputation not counted), over the window that
-closes when the last of them completes."""
+window times the combined peak of the cell's chips (%). Serving counts
+every decode and prefill call that ran inside the window; training counts
+every step started in it (forward and backward, recomputation not
+counted), over the window that closes when the last of them completes."""
 
 from chipbench.engine_calls import inside, model_calls
 
@@ -15,4 +15,4 @@ def read(run):
         f = sum(c.flops for c in inside(model_calls(run), t0, t1))
     else:
         return None
-    return 100.0 * f / ((t1 - t0) * run.peaks["flops_per_s"])
+    return 100.0 * f / ((t1 - t0) * run.chips * run.peaks["flops_per_s"])

@@ -75,6 +75,9 @@ def main(argv=None):
     enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    # never evict, whatever the environment caps: one cell compiling must
+    # not push another cell's programs out
+    jax.config.update("jax_compilation_cache_max_size", -1)
 
     result = run_cell(cell, args.seed, args.seconds, bool(args.trace), peaks)
     d = devs[0]
@@ -101,7 +104,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, peaks: dict,
     from chipbench.common import Run
     from chipbench.reference import arch_from_config
 
-    run = Run(cell=cell, seed=seed, seconds=seconds, peaks=peaks)
+    run = Run(cell=cell, seed=seed, seconds=seconds, peaks=peaks,
+              chips=cell.chips)
     compiles: list[float] = []
     jax.monitoring.register_event_duration_secs_listener(
         lambda ev, secs, **_: compiles.append(time.perf_counter())
@@ -144,6 +148,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, peaks: dict,
         out["breakdown"] = {
             "device_ops": progs + trace_reduce.top(t.ops, 10 - len(progs)),
             "idle_gaps": trace_reduce.top(t.idle_by_span, 10)}
+        if t.collectives:
+            run.notes["collective_ops"] = trace_reduce.top(t.collectives, 5)
     t0, t1 = run.window
     out["notes"] = dict(run.notes, readings=prog,
                         compiles_in_setup=sum(t < t0 for t in compiles),

@@ -62,7 +62,7 @@ def main(argv=None):
     jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
     run = Run(cell=cell, seed=args.seed, seconds=args.seconds,
-              peaks=peaks_for(dev.device_kind))
+              peaks=peaks_for(dev.device_kind), chips=cell.chips)
     trace_dir = args.keep or tempfile.mkdtemp(prefix="scope_breakdown_")
     if cell.mix["kind"] == "serve":
         from chipbench.serve_cell import run_serve as drive

@@ -113,3 +113,23 @@ def test_training_readers():
                        (8.0, 12.0, 16384, 1e15, 8.8)]
     assert read("train_tokens_per_s", run) == pytest.approx(3 * 16384 / 12)
     assert read("mfu.train", run) == pytest.approx(100 * 3e15 / (12 * 197e12))
+
+
+def test_one_chip_readings_are_those_of_the_one_chip_formulas():
+    """``chips`` defaults to 1 and then divides nothing: the readers give,
+    digit for digit, what they gave before they divided by the chips."""
+    run = serve_run()
+    run.trace = types.SimpleNamespace(window_s=2.0, busy_s=1.5, devices=1,
+                                      programs={"decode_step": 0.5})
+    run.trace_window = (100.9, 101.1)
+    assert run.chips == 1
+    calls = model_calls(run)
+    need = sum(flops.roofline_s(c.flops, c.nbytes, PEAKS) for c in calls
+               if c.kind == "decode")
+    assert read("decode_step_roofline.chat", run) == 100.0 * need / 0.5
+    f = sum(c.flops for c in calls)
+    assert read("mfu.chat", run) == 100.0 * f / (10.0 * PEAKS["flops_per_s"])
+    run.chips = 4
+    assert read("decode_step_roofline.chat-tp4", run) == pytest.approx(25.0 * need / 0.5)
+    assert read("mfu.chat-tp4", run) == pytest.approx(
+        25.0 * f / (10.0 * PEAKS["flops_per_s"]))

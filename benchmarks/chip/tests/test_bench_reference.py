@@ -74,3 +74,50 @@ def test_weights_from_seed():
     assert not np.array_equal(np.asarray(a["blocks"]["attn"]["wq"]),
                               np.asarray(c["blocks"]["attn"]["wq"]))
     assert np.all(np.asarray(a["final_norm"]["w"]) == 1.0)
+
+
+def test_untied_head_matches_program_forward():
+    """A configuration that says ``"tie_embeddings": false`` is read
+    through its own ``lm_head``, and asking for some rows gives those rows
+    of the whole forward pass."""
+    cell, model, params = tiny("internlm2-20b.chat-tp4")
+    arch = arch_from_config(cell.config)
+    assert not arch.tie_embeddings and "lm_head" in params
+    assert arch_from_config(bench_tiny.tiny_cell("granite-3-2b.chat").config).tie_embeddings
+    toks = np.random.default_rng(1).integers(1, 256, size=29)
+    want = model.forward(params, {"tokens": jnp.asarray(toks[None])})[0][0]
+    got = reference.serve_logits(params, list(toks), arch, pad_to=16)[:29]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4, rtol=2e-4)
+    rows = np.array([3, 17, 28])
+    some = reference.serve_logits(params, list(toks), arch, pad_to=16, rows=rows)
+    np.testing.assert_allclose(np.asarray(some), np.asarray(got)[rows], atol=1e-6)
+
+
+def test_head_in_vocabulary_blocks_is_the_whole_head(monkeypatch):
+    cell, model, params = tiny("internlm2-20b.chat-tp4")
+    arch = arch_from_config(cell.config)
+    toks = list(np.random.default_rng(2).integers(1, 256, size=20))
+    for precision in ("f32", "fp8"):
+        whole = reference.serve_logits(params, toks, arch, precision, pad_to=16)
+        monkeypatch.setattr(reference, "VOCAB_BLOCK", 100)     # 3 blocks
+        blocks = reference.serve_logits(params, toks, arch, precision, pad_to=16)
+        monkeypatch.undo()
+        # float32 rounding: the backend may split the sum differently
+        np.testing.assert_allclose(np.asarray(blocks), np.asarray(whole),
+                                   atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("name", ["granite-3-2b.chat", "internlm2-20b.chat-tp4"])
+def test_head_on_rows_is_the_head_on_every_row(name):
+    """The rows compared, in vocabulary blocks, read what the head over
+    every row (``_mm``, one tensor) reads, in float32 and in the fp8
+    control."""
+    cell, model, params = tiny(name)
+    arch = arch_from_config(cell.config)
+    x = jax.random.normal(jax.random.key(4), (48, arch.d_model), jnp.float32)
+    rows = jnp.asarray([0, 5, 40, 47], jnp.int32)
+    for precision in ("f32", "fp8"):
+        want = reference._head(params, x, arch, precision)[rows]
+        got = reference._head_rows(params, x, rows, arch, precision)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-5, rtol=1e-5)

@@ -55,3 +55,48 @@ def test_no_device_work_is_no_busy():
     w, _, _, host = fixture()
     t = summarize(w, [[]], [[]], host)
     assert t.devices == 0 and t.busy_s == 0.0
+
+
+def test_collective_ops_by_kind():
+    from chipbench.trace_reduce import is_collective, op_kind
+    assert op_kind("f32[8,128]{1,0} all-reduce(f32[8,128]{1,0} %x), "
+                   "replica_groups={{0,1,2,3}}") == "all-reduce"
+    assert op_kind("(bf16[8,6144]{1,0:T(8,128)(2,1)}, u32[]) all-reduce-start("
+                   "bf16[8,6144]{1,0} %p)") == "all-reduce-start"
+    assert op_kind("f32[4]{0:T(256)} fusion(f32[4]{0} %a), kind=kLoop") == "fusion"
+    yes = [Event("%all-reduce.7", 0, 1), Event("all-gather-start.2", 0, 1),
+           Event("_reduce-scatter.1", 0, 1), Event("collective-permute-done", 0, 1),
+           Event("%fusion.12", 0, 1, kind="all-reduce"), Event("all-to-all.3", 0, 1)]
+    no = [Event("%fusion.12", 0, 1, kind="fusion"), Event("reduce.4", 0, 1),
+          Event("all_reduce_like_name", 0, 1), Event("%copy-start.1", 0, 1)]
+    from types import SimpleNamespace
+    from chipbench.trace_reduce import op_event
+    e = op_event(SimpleNamespace(
+        name="%all-reduce.5.clone = bf16[8,6144]{1,0} all-reduce(bf16[8,6144]{1,0} %dot)",
+        start_ns=10, end_ns=20))
+    assert (e.name, e.start, e.end, e.kind) == ("%all-reduce.5.clone", 10, 20, "all-reduce")
+    yes.append(op_event(SimpleNamespace(
+        name="%fusion.9 = bf16[8,6144]{1,0} all-reduce-done(%ars)", start_ns=0, end_ns=1)))
+    assert all(map(is_collective, yes))
+    assert not any(map(is_collective, no))
+
+
+def test_collective_time_averaged_over_chips():
+    # two chips: chip 0 all-reduces 10 ms of its 40 busy, chip 1 20 of 40
+    # (a start/done pair, the done half partly outside the window)
+    w = (0.0, 100 * MS)
+    chip0 = [Event("fusion.1", 0, 30 * MS), Event("%all-reduce.3", 30 * MS, 40 * MS)]
+    chip1 = [Event("fusion.1", 0, 20 * MS),
+             Event("all-reduce-start.3", 20 * MS, 25 * MS, kind="all-reduce-start"),
+             Event("all-reduce-done.3", 85 * MS, 115 * MS, kind="all-reduce-done")]
+    t = summarize(w, [chip0, chip1], [[], []], [])
+    assert t.busy_s == pytest.approx(0.040)
+    assert t.collective_s == pytest.approx(0.015)
+    assert t.collectives == pytest.approx({"%all-reduce.3": 0.005,
+                                           "all-reduce-start.3": 0.0025,
+                                           "all-reduce-done.3": 0.0075})
+    from chipbench import manifest
+    read = manifest.metric_reader("collective_share.chat-tp4")
+    assert read(type("R", (), {"trace": t})) == pytest.approx(37.5)
+    assert summarize(*fixture()).collective_s == 0.0
+    assert read(type("R", (), {"trace": summarize(*fixture())})) is None
